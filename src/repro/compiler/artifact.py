@@ -354,10 +354,12 @@ class CompileResult:
         choke point, so ``compile(..., verify=)``, the store's
         verify-on-load policies, and ``inspect --verify`` all inherit it.
         A *disproven* mapping raises ``AssertionError`` from either
-        engine; a batched-backend *fault* (injected OSError, jax runtime
-        failure) degrades to the scalar oracle rather than skipping
-        verification — an unverified artifact is never reported
-        verified."""
+        engine.  An ``OSError`` from the batched path (the fault harness's
+        ``sim.batch`` site) degrades to the scalar oracle rather than
+        skipping verification — an unverified artifact is never reported
+        verified.  Any other backend fault, a jax runtime error on the
+        device above all, raises: a device failure must fail the verify,
+        not hide behind the host oracle."""
         from repro.compiler.errors import MappingInfeasible
         from repro.core.simulate import simulate as _simulate
 
@@ -378,7 +380,7 @@ class CompileResult:
                                        prepared=prepared)
             except AssertionError:
                 raise  # a genuine disproof — exactly what verify is for
-            except (OSError, RuntimeError) as e:
+            except OSError as e:
                 print(
                     f"warning: batched verify backend failed "
                     f"({type(e).__name__}: {e}); degrading to the scalar "

@@ -23,8 +23,8 @@ golden records in ``tests/golden_ii_quick.json``.
 Verification (``verify=True``, and every store verify-on-load policy)
 funnels through ``CompileResult.simulate``: multi-segment artifacts run
 the batched simulator (``repro.sim``, backend selected via
-``REPRO_SIM_BACKEND``) and degrade to the frozen scalar oracle on backend
-faults — see ``docs/simulator.md``.
+``REPRO_SIM_BACKEND``); only an ``OSError`` there degrades to the frozen
+scalar oracle, and a device failure raises — see ``docs/simulator.md``.
 """
 from __future__ import annotations
 

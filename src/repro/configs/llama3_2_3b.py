@@ -1,7 +1,9 @@
 """Llama-3.2-3B — small llama3 dense GQA transformer.
 
 [dense] 28L d_model=3072 24H (GQA kv=8) d_ff=8192 vocab=128256
-[hf:meta-llama/Llama-3.2-1B; unverified]
+[hf:meta-llama/Llama-3.2-3B config.json: 28 layers, hidden 3072, 24 heads,
+ 8 KV heads, intermediate 8192, vocab 128256, rope_theta 500000, tied
+ embeddings]
 """
 from repro.configs.base import ModelConfig, register
 

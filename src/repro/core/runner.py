@@ -32,6 +32,12 @@ Workers learn their attempt index through the
 :mod:`repro.compiler.faultinject` — attempt-scoped fault specs model
 transient faults that heal on retry).
 
+Workers never touch the accelerator: each pins the batched simulator to
+the host ``numpy`` backend (``REPRO_SIM_BACKEND``) before its task runs.
+A chip belongs to one process at a time, so device verification happens
+only in single-process paths (``plaid-compile verify``, ``collect
+--batch-verify`` after the sweep, ``chip_smoke.py``).
+
 The task function and the task payloads must be picklable top-level
 objects under the ``spawn`` start method; under ``fork`` (the Linux
 default) anything goes.  Results stream back in completion order, like
@@ -53,6 +59,7 @@ from repro.compiler.errors import (
     classify,
 )
 from repro.compiler.faultinject import ATTEMPT_VAR
+from repro.sim.batch import ENV_BACKEND
 
 #: grace between SIGTERM and SIGKILL when reclaiming a timed-out worker
 _TERM_GRACE_S = 1.0
@@ -89,6 +96,7 @@ def _child_main(fn: Callable, task, attempt: int, conn_w) -> None:
     names, message, traceback) over the pipe, exit.  Top-level so the
     ``spawn`` start method can import it."""
     os.environ[ATTEMPT_VAR] = str(attempt)
+    os.environ[ENV_BACKEND] = "numpy"
     try:
         result = fn(task)
         payload = ("ok", result)

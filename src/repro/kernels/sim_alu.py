@@ -13,24 +13,31 @@ branch-free, as TPU vector hardware requires (and exactly what the
 domain-hardwired PCU of the paper does in silicon: all functional units
 compute, the configuration selects).
 
-On CPU hosts (this container) the kernel executes with
-``interpret=True`` via the same ``auto_interpret()`` convention as
-``repro.kernels.ops``; ``repro.sim.step`` additionally wraps the call in
-a capability breaker that falls back to plain jnp if Pallas cannot run
-at all.
+Because the stage is elementwise, the ``(B, N)`` operands are flattened
+into a lane-dense ``(rows, 128)`` view and walked by a 1-D row grid of
+fixed-size blocks: VMEM use is set by the block, not by the batch, so a
+bucket the size of an architecture sweep compiles like a small one.
+
+On CPU hosts the kernel executes with ``interpret=True`` (the same
+``jax.default_backend()`` convention as ``repro.kernels.ops``).  A failure
+of the kernel raises; nothing falls back to plain jnp.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from repro.sim.lower import OPS
 from repro.sim.step import _jnp_alu
 
-#: float32 VPU tile (sublane x lane)
-_TILE_R, _TILE_C = 8, 128
+#: lanes of the flattened view (one VPU vreg row)
+_LANES = 128
+#: rows per grid block: 512 x 128 f32 = 256 KiB per operand, six operands
+#: double-buffered stay far inside the default scoped-VMEM limit
+_BLOCK_ROWS = 512
+#: float32 sublane count (block rows must be a multiple of it)
+_SUBLANES = 8
 
 
 def _kernel(code_ref, a_ref, b_ref, c_ref, leaf_ref, o_ref):
@@ -45,31 +52,30 @@ def _kernel(code_ref, a_ref, b_ref, c_ref, leaf_ref, o_ref):
     o_ref[...] = out
 
 
-def _pad_to(x, rows: int, cols: int):
-    r, c = x.shape
-    return jnp.pad(x, ((0, rows - r), (0, cols - c)))
-
-
 def sim_alu(opcode, a, b, c, leaf, *, interpret: bool = None):
-    """Elementwise ``_apply(opcode, a, b, c, leaf)`` over (B, N) float32
-    arrays (any 2-D shape; padded to VPU tiles internally)."""
+    """Elementwise ``_apply(opcode, a, b, c, leaf)`` over float32 arrays
+    of any one shape; the result has that shape."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    rows_, cols_ = opcode.shape
-    rows = -(-rows_ // _TILE_R) * _TILE_R
-    cols = -(-cols_ // _TILE_C) * _TILE_C
-    args = [
-        _pad_to(opcode.astype(jnp.int32), rows, cols),
-        _pad_to(a.astype(jnp.float32), rows, cols),
-        _pad_to(b.astype(jnp.float32), rows, cols),
-        _pad_to(c.astype(jnp.float32), rows, cols),
-        _pad_to(leaf.astype(jnp.float32), rows, cols),
-    ]
+    shape = opcode.shape
+    n = opcode.size
+    rows = -(-n // _LANES)
+    block = min(_BLOCK_ROWS, -(-rows // _SUBLANES) * _SUBLANES)
+    rows = -(-rows // block) * block
+
+    def lanes(x, dtype):
+        x = x.astype(dtype).reshape(-1)
+        return jnp.pad(x, (0, rows * _LANES - n)).reshape(rows, _LANES)
+
+    args = [lanes(opcode, jnp.int32)] + [
+        lanes(x, jnp.float32) for x in (a, b, c, leaf)]
+    spec = pl.BlockSpec((block, _LANES), lambda i: (i, 0))
     out = pl.pallas_call(
         _kernel,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)] * 5,
-        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((rows, cols), jnp.float32),
+        grid=(rows // block,),
+        in_specs=[spec] * 5,
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct((rows, _LANES), jnp.float32),
         interpret=interpret,
     )(*args)
-    return out[:rows_, :cols_]
+    return out.reshape(-1)[:n].reshape(shape)

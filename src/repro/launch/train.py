@@ -12,6 +12,7 @@ import logging
 
 from repro.configs import SHAPES, RunConfig, get_config, smoke_config
 from repro.configs.base import ShapeSpec
+from repro.launch.compile_cache import enable_compile_cache
 from repro.train.loop import train
 
 
@@ -28,6 +29,7 @@ def main():
     ap.add_argument("--grad-compression", default="none", choices=["none", "int8"])
     args = ap.parse_args()
     logging.basicConfig(level=logging.INFO)
+    enable_compile_cache()
 
     if args.smoke:
         cfg = smoke_config(args.arch)

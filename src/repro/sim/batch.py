@@ -35,7 +35,8 @@ same artifacts again under a different backend / on every load".
 Fault injection: the ``sim.batch`` site fires at entry
 (``REPRO_FAULTS``), so chaos tests can crash/hang/OSError the batched
 verify path; ``CompileResult.simulate`` degrades to the scalar oracle on
-backend faults rather than serving unverified artifacts.
+that ``OSError`` rather than serving unverified artifacts, and lets every
+other backend fault (a jax runtime error on the device) raise.
 """
 from __future__ import annotations
 
@@ -280,8 +281,7 @@ def simulate_batch(mappings, iterations: int = 4, backend: str = "auto",
     mapping, in input order, plus throughput metadata.  Never raises on a
     *failing mapping* (that is a ``False`` verdict); raises on backend /
     environment faults (``OSError`` from fault injection, jax runtime
-    errors), which ``CompileResult.simulate`` treats as "degrade to the
-    scalar oracle".
+    errors, a failing Pallas kernel).
 
     Pass ``prepared`` (from :func:`prepare_batch` over the *same*
     mappings/iterations) to skip the lowering + packing half and rerun

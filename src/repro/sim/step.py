@@ -50,8 +50,7 @@ Backends:
   two-phase state machine traced once per bucket shape, for accelerator
   execution.
 * ``pallas`` — the jnp backend with the ALU apply stage running as a
-  Pallas kernel (``repro.kernels.sim_alu``), behind a capability check
-  with a clean fallback to plain jnp on hosts where Pallas cannot run.
+  Pallas kernel (``repro.kernels.sim_alu``); a kernel failure raises.
 
 Final comparison against the ``ref`` oracle lives in ``repro.sim.batch``
 (it is tolerance-policy dependent; see ``repro.sim.check``).
@@ -316,24 +315,6 @@ def run_bucket_numpy(pb: PackedBucket):
 # -- jnp backend (optional Pallas ALU stage) ---------------------------------
 
 
-def have_jax() -> bool:
-    try:
-        import jax  # noqa: F401
-        return True
-    except Exception:  # pragma: no cover - jax is baked into this image
-        return False
-
-
-_pallas_broken = False
-
-
-def pallas_available() -> bool:
-    """Capability check for the Pallas ALU stage: jax importable and the
-    kernel not previously observed to fail on this host (first failure
-    trips a sticky breaker; callers fall back to plain jnp)."""
-    return have_jax() and not _pallas_broken
-
-
 def _jnp_alu(jnp, code: int, a, b, c, leaf):
     op = OPS[code]
     if op in ("const", "input", "load"):
@@ -471,18 +452,11 @@ def _jit_runner(hmax: int, iterations: int, shape: Tuple[int, ...],
     return jax.jit(run)
 
 
-def run_bucket_jnp(pb: PackedBucket, use_pallas: bool = False):
-    """jnp backend: same contract as :func:`run_bucket_numpy` (values are
-    float32 upcast to float64 — compare under ``F32_TOL``).  With
-    ``use_pallas`` the ALU apply stage runs as a Pallas kernel; a failure
-    there trips the capability breaker and re-runs on plain jnp."""
-    global _pallas_broken
+def device_args(pb: PackedBucket):
+    """The bucket's arrays as the jitted cycle loop takes them."""
     import jax.numpy as jnp
 
-    if use_pallas and not pallas_available():
-        use_pallas = False
-    runner = _jit_runner(pb.hmax, pb.iterations, pb.shape, use_pallas)
-    args = (
+    return (
         jnp.asarray(pb.ii), jnp.asarray(pb.horizon),
         jnp.asarray(pb.opcode), jnp.asarray(pb.exec_mask),
         jnp.asarray(pb.issue), jnp.asarray(pb.leaf, dtype=jnp.float32),
@@ -492,16 +466,15 @@ def run_bucket_jnp(pb: PackedBucket, use_pallas: bool = False):
         jnp.asarray(pb.op_steps), jnp.asarray(pb.step_src),
         jnp.asarray(pb.step_abs),
     )
-    try:
-        val, done, fail = runner(*args)
-    except Exception:
-        if not use_pallas:
-            raise
-        # Pallas lowering/execution failed on this host: break the
-        # capability and serve the request on plain jnp instead
-        _pallas_broken = True
-        val, done, fail = _jit_runner(
-            pb.hmax, pb.iterations, pb.shape, False)(*args)
+
+
+def run_bucket_jnp(pb: PackedBucket, use_pallas: bool = False):
+    """jnp backend: same contract as :func:`run_bucket_numpy` (values are
+    float32 upcast to float64 — compare under ``F32_TOL``).  With
+    ``use_pallas`` the ALU apply stage runs as a Pallas kernel; a failure
+    there raises like any other backend fault."""
+    runner = _jit_runner(pb.hmax, pb.iterations, pb.shape, use_pallas)
+    val, done, fail = runner(*device_args(pb))
     return (np.asarray(val, dtype=np.float64), np.asarray(done),
             np.asarray(fail))
 

@@ -195,6 +195,12 @@ def _task_boom(task):
     raise ValueError("deterministic bug")
 
 
+def _task_sim_backend(task):
+    from repro.sim.batch import ENV_BACKEND, select_backend
+
+    return os.environ.get(ENV_BACKEND), select_backend("auto")
+
+
 def _drain(stream):
     oks, fails = {}, {}
     for task, status, payload in stream:
@@ -256,6 +262,16 @@ def test_runner_mixed_grid_completes():
     runner = SupervisedRunner(_task_ok, jobs=2, retries=0, label=label)
     oks, fails = _drain(runner.run(list(range(7))))
     assert len(oks) == 7 and not fails
+
+
+def test_runner_workers_pin_host_sim_backend(monkeypatch):
+    """A chip belongs to one process: a worker verifies on the host numpy
+    backend even when the parent asked for a device backend."""
+    monkeypatch.setenv("REPRO_SIM_BACKEND", "jnp")
+    oks, fails = _drain(run_supervised(_task_sim_backend, ["w"]))
+    assert not fails
+    assert oks["w"] == ("numpy", "numpy")
+    assert os.environ["REPRO_SIM_BACKEND"] == "jnp"  # parent untouched
 
 
 # -- cooperative deadlines ----------------------------------------------------

@@ -83,3 +83,36 @@ def test_sliding_window_ring_buffer():
         np.asarray(logits[:, 0], np.float32), np.asarray(ref[:, T + 5], np.float32),
         rtol=0.12, atol=0.25,
     )
+
+
+def test_serve_launcher_is_seeded():
+    """``launch.serve.serve`` (the path ``chip_smoke.py`` drives at full
+    width) answers one batch, and the same seed gives the same tokens."""
+    from repro.launch.serve import serve
+
+    cfg = smoke_config("llama3_2_3b").replace(n_layers=2)
+    _, prompts, t_a, info = serve(cfg, batch=2, prompt_len=8, new_tokens=3,
+                                  seed=5)
+    _, _, t_b, _ = serve(cfg, batch=2, prompt_len=8, new_tokens=3, seed=5)
+    assert prompts.shape == (2, 8) and t_a.shape == (2, 3)
+    assert info["cache_length"] == 8 + 3 - 1
+    np.testing.assert_array_equal(np.asarray(t_a), np.asarray(t_b))
+
+
+def test_compile_cache_placement(monkeypatch):
+    """``JAX_COMPILATION_CACHE_DIR`` wins and no other directory is set;
+    without it the cache sits at the fixed ``<repo>/.jax_cache``."""
+    from repro.launch import compile_cache as cc
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/jc")
+        assert cc.enable_compile_cache() == "/elsewhere/jc"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        want = str(cc.REPO_CACHE_DIR)
+        assert cc.enable_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+        assert cc.REPO_CACHE_DIR.parent.joinpath("pyproject.toml").exists()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -16,11 +16,15 @@ per-``(node, iter)`` values.  These tests pin that contract:
   ``PreparedBatch`` is rejected loudly;
 * an injected backend fault (``sim.batch`` site) degrades
   ``CompileResult.simulate`` to the scalar oracle instead of serving an
-  unverified artifact.
+  unverified artifact, while a failing Pallas kernel raises;
+* the row-gridded Pallas ALU kernel equals the jnp where-ladder at shapes
+  that are not multiples of its tile.
 """
 import copy
 import json
 
+import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from _hypothesis_shim import given, settings, strategies as st
@@ -39,8 +43,10 @@ from repro.sim import (
     simulate_batch,
     verify_mappings,
 )
+from repro.sim import step
 from repro.sim.check import DEFAULT_TOL, close, assert_differential
-from repro.sim.step import NEVER
+from repro.sim.lower import OPS
+from repro.sim.step import NEVER, apply_ops_jnp
 
 # (workload, unroll): atax_u2 is the quick-grid staple, dwconv_u1 a deep
 # mul/mac chain, jacobi_u1 carries a distance>0 recurrence edge
@@ -257,3 +263,46 @@ def test_compile_result_degrades_to_scalar_on_backend_fault(capsys):
     for g, w in zip(got, want):
         assert set(g) == set(w)
         assert all(close(g[k], w[k], DEFAULT_TOL) for k in w)
+
+
+def test_pallas_failure_raises_instead_of_falling_back(mappings, monkeypatch):
+    """A failing Pallas kernel fails the verify: ``simulate_batch`` raises
+    rather than answering on plain jnp, and ``CompileResult.simulate``
+    does not hide it behind the scalar oracle."""
+    import repro.kernels.sim_alu as sim_alu_mod
+
+    def broken(*args, **kwargs):
+        raise RuntimeError("pallas kernel failed")
+
+    monkeypatch.setattr(sim_alu_mod, "sim_alu", broken)
+    step._jit_runner.cache_clear()
+    try:
+        with pytest.raises(RuntimeError, match="pallas kernel failed"):
+            simulate_batch(mappings, iterations=3, backend="pallas")
+        res = compile("atax", unroll=2)
+        res.mappings = res.mappings + [copy.deepcopy(res.mappings[0])]
+        monkeypatch.setenv("REPRO_SIM_BACKEND", "pallas")
+        with pytest.raises(RuntimeError, match="pallas kernel failed"):
+            res.simulate(iterations=3)
+    finally:
+        step._jit_runner.cache_clear()
+    monkeypatch.undo()
+    assert all(v.backend == "pallas" and v.ok for v in simulate_batch(
+        mappings, iterations=3, backend="pallas"))
+
+
+@pytest.mark.parametrize("shape", [(1, 1), (3, 5), (9, 130), (77, 64),
+                                   (1030, 70)])
+def test_sim_alu_grid_matches_jnp(shape):
+    """Shapes off the (8, 128) tile and, at (1030, 70), more than one
+    512-row block of the lane-dense view."""
+    from repro.kernels.sim_alu import sim_alu
+
+    rng = np.random.default_rng(sum(shape))
+    code = jnp.asarray(rng.integers(0, len(OPS), shape), jnp.int32)
+    a, b, c, leaf = (jnp.asarray(rng.integers(-50, 50, shape), jnp.float32)
+                     for _ in range(4))
+    got = sim_alu(code, a, b, c, leaf, interpret=True)
+    assert got.shape == shape
+    np.testing.assert_array_equal(
+        np.asarray(got), np.asarray(apply_ops_jnp(code, a, b, c, leaf)))

@@ -81,13 +81,8 @@ def test_checkpoint_elastic_reshard(tmp_path):
            "step": jnp.zeros((), jnp.int32)}
     ckpt_lib.save(str(tmp_path), 5, {"params": params, "opt_state": opt, "extra": {"x": 1}})
     assert ckpt_lib.latest_step(str(tmp_path)) == 5
-    # axis_types= (and jax.sharding.AxisType) only exist on newer jax;
-    # default axis types are equivalent for this single-axis mesh
-    if hasattr(jax.sharding, "AxisType"):
-        mesh = jax.make_mesh((1,), ("data",),
-                             axis_types=(jax.sharding.AxisType.Auto,))
-    else:
-        mesh = jax.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     sh = jax.NamedSharding(mesh, jax.sharding.PartitionSpec("data"))
     rep = jax.NamedSharding(mesh, jax.sharding.PartitionSpec())
     pick = lambda x: sh if getattr(x, "ndim", 0) >= 1 else rep
