@@ -439,15 +439,11 @@ def _cmd_verify(args) -> int:
 
     # cold = lower + pack + run; warm = rerun on the cached PreparedBatch
     # (the serving-tier shape: artifacts re-verified on every load)
-    t0 = time.perf_counter()
     cold = simulate_batch(mappings, iterations=args.iterations,
                           backend=args.backend)
-    t_cold = time.perf_counter() - t0
     prepared = prepare_batch(mappings, iterations=args.iterations)
-    t0 = time.perf_counter()
-    simulate_batch(mappings, iterations=args.iterations,
-                   backend=args.backend, prepared=prepared)
-    t_warm = time.perf_counter() - t0
+    warm = simulate_batch(mappings, iterations=args.iterations,
+                          backend=args.backend, prepared=prepared)
     for (row, s), v in zip(owners, cold):
         if not v.ok and row["fail"] is None:
             row["fail"] = f"segment {s}: {v.reason}"
@@ -464,12 +460,13 @@ def _cmd_verify(args) -> int:
                   f"{row['segments']} mapping(s) verified")
 
     n = len(mappings)
-    cold_mps = n / t_cold if t_cold > 0 else 0.0
-    warm_mps = n / t_warm if t_warm > 0 else 0.0
+    cold_mps, warm_mps = cold.mappings_per_s, warm.mappings_per_s
     print(f"batched[{cold.backend}]: {n} mappings, "
           f"{cold.n_buckets} bucket(s), "
           f"{cold.n_scalar_fallback} scalar fallback(s); "
           f"cold {cold_mps:.0f} mappings/s, warm {warm_mps:.0f} mappings/s")
+    print(f"batched cold {cold.describe()}")
+    print(f"batched warm {warm.describe()}")
 
     scalar_mps = None
     if args.parity:

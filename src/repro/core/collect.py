@@ -371,6 +371,7 @@ def _batch_verify_store(store_path: str, iterations: int = 3) -> Dict:
     print(f"batch-verify[{result.backend}]: {len(mappings)} mapping(s), "
           f"{failed} failure(s), "
           f"{result.mappings_per_s:.0f} mappings/s", flush=True)
+    print(f"batch-verify {result.describe()}", flush=True)
     return {
         "backend": result.backend,
         "mappings": len(mappings),

@@ -77,5 +77,6 @@ def sim_alu(opcode, a, b, c, leaf, *, interpret: bool = None):
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((rows, _LANES), jnp.float32),
         interpret=interpret,
+        name="sim_alu",
     )(*args)
     return out.reshape(-1)[:n].reshape(shape)

@@ -32,6 +32,13 @@ exposed separately: :func:`prepare_batch` lowers + packs once, and
 cached :class:`PreparedBatch` — the serving-tier shape for "verify the
 same artifacts again under a different backend / on every load".
 
+Spans and counters: every call records its phases — ``sim.simulate_batch``
+(the root, whole call), ``sim.prepare`` (cold calls), ``sim.scalar_fallback``,
+``sim.upload``, ``sim.cycle_loop``, ``sim.pullback`` and ``sim.check`` — and
+its :data:`COUNTERS` on the :class:`BatchResult` it returns, each span also
+a profiler ``TraceAnnotation`` of the same name (``repro.sim.spans``).
+``BatchResult.wall_s`` is the root span's length.
+
 Fault injection: the ``sim.batch`` site fires at entry
 (``REPRO_FAULTS``), so chaos tests can crash/hang/OSError the batched
 verify path; ``CompileResult.simulate`` degrades to the scalar oracle on
@@ -41,7 +48,6 @@ other backend fault (a jax runtime error on the device) raise.
 from __future__ import annotations
 
 import os
-import time
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
 
@@ -50,10 +56,16 @@ import numpy as np
 from repro.compiler import faultinject
 from repro.sim.check import Tolerance, close_array, tolerance_for
 from repro.sim.lower import CompiledSim, LoweringUnsupported, lower_mapping
+from repro.sim.spans import Span, record, span
 from repro.sim.step import NEVER, PackedBucket, run_bucket
 
 ENV_BACKEND = "REPRO_SIM_BACKEND"
 BACKENDS = ("numpy", "jnp", "pallas")
+#: the root span of every call; its children are the call's phases
+ROOT_SPAN = "sim.simulate_batch"
+#: counters of every call: bytes sent to and pulled back from the
+#: device, and cycle-loop runners built (``step._jit_runner`` misses)
+COUNTERS = ("upload_bytes", "pullback_bytes", "runner_builds")
 
 
 def select_backend(backend: str = "auto") -> str:
@@ -100,17 +112,44 @@ class SimVerdict:
 
 
 class BatchResult(list):
-    """``list[SimVerdict]`` plus run metadata (backend, wall seconds,
-    bucket count, scalar fallbacks)."""
+    """``list[SimVerdict]`` plus run metadata: backend, bucket count,
+    scalar fallbacks, and the call's ``spans`` (in start order, the root
+    ``sim.simulate_batch`` first) and ``counters`` (:data:`COUNTERS`; see
+    ``repro.sim.spans``)."""
 
-    backend: str = "numpy"
-    wall_s: float = 0.0
-    n_buckets: int = 0
-    n_scalar_fallback: int = 0
+    def __init__(self, verdicts=()):
+        super().__init__(verdicts)
+        self.backend = "numpy"
+        self.n_buckets = 0
+        self.n_scalar_fallback = 0
+        self.spans: List[Span] = []
+        self.counters: Dict[str, int] = dict.fromkeys(COUNTERS, 0)
+
+    @property
+    def wall_s(self) -> float:
+        """Seconds of the root span: the whole call."""
+        root = next((sp for sp in self.spans if sp.parent is None), None)
+        return (root.end_ns - root.start_ns) / 1e9 if root else 0.0
 
     @property
     def mappings_per_s(self) -> float:
         return len(self) / self.wall_s if self.wall_s > 0 else 0.0
+
+    def phases_ms(self) -> Dict[str, float]:
+        """Milliseconds of each phase of the call (the root's children,
+        summed by name, in the order they first ran)."""
+        out: Dict[str, float] = {}
+        for sp in self.spans:
+            if sp.parent == ROOT_SPAN:
+                out[sp.name] = out.get(sp.name, 0.0) + sp.ms
+        return out
+
+    def describe(self) -> str:
+        """One line of phases and counters, for operators."""
+        phases = ", ".join(f"{name[len('sim.'):]} {ms:.1f}"
+                           for name, ms in self.phases_ms().items())
+        counters = ", ".join(f"{k}={v}" for k, v in self.counters.items())
+        return f"phases (ms): {phases or 'none'}; {counters}"
 
 
 def _pow2(x: int) -> int:
@@ -229,40 +268,44 @@ def _values_thunk(val_b: np.ndarray, done_b: np.ndarray, node_ids):
 def _bucket_verdicts(forms: List[CompiledSim], pb: PackedBucket,
                      backend: str, tol: Tolerance) -> List[SimVerdict]:
     val, done, read_fail = run_bucket(pb, backend)
-    # whole-batch checks (padding rows carry compare=False, so they never
-    # contribute); the per-form loop below only details the failures
-    cmpI = pb.compare[:, :, None]
-    missing = cmpI & ~done
-    bad = cmpI & done & ~close_array(val, pb.ref, tol)
-    missing_any = missing.any(axis=(1, 2))
-    bad_any = bad.any(axis=(1, 2))
-    out: List[SimVerdict] = []
-    for b, cs in enumerate(forms):
-        n = cs.n_nodes
-        if cs.fail_static is not None:
-            out.append(SimVerdict(False, cs.fail_static, backend=backend))
-        elif read_fail[b]:
-            out.append(SimVerdict(
-                False, "operand value not present at read time "
-                       "(missing / unrouted / mistimed route)",
-                backend=backend))
-        elif missing_any[b]:
-            r, it = np.argwhere(missing[b])[0]
-            out.append(SimVerdict(
-                False, f"node {cs.node_ids[r]} iter {it}: no value produced",
-                backend=backend))
-        elif bad_any[b]:
-            r, it = np.argwhere(bad[b])[0]
-            out.append(SimVerdict(
-                False,
-                f"node {cs.node_ids[r]} iter {it}: got {val[b, r, it]}, "
-                f"want {cs.ref[r, it]}", backend=backend))
-        else:
-            out.append(SimVerdict(
-                True, backend=backend,
-                values_thunk=_values_thunk(
-                    val[b, :n, :], done[b, :n, :], cs.node_ids)))
-    return out
+    with span("sim.check"):
+        # whole-batch checks (padding rows carry compare=False, so they
+        # never contribute); the per-form loop below only details the
+        # failures
+        cmpI = pb.compare[:, :, None]
+        missing = cmpI & ~done
+        bad = cmpI & done & ~close_array(val, pb.ref, tol)
+        missing_any = missing.any(axis=(1, 2))
+        bad_any = bad.any(axis=(1, 2))
+        out: List[SimVerdict] = []
+        for b, cs in enumerate(forms):
+            n = cs.n_nodes
+            if cs.fail_static is not None:
+                out.append(SimVerdict(False, cs.fail_static,
+                                      backend=backend))
+            elif read_fail[b]:
+                out.append(SimVerdict(
+                    False, "operand value not present at read time "
+                           "(missing / unrouted / mistimed route)",
+                    backend=backend))
+            elif missing_any[b]:
+                r, it = np.argwhere(missing[b])[0]
+                out.append(SimVerdict(
+                    False,
+                    f"node {cs.node_ids[r]} iter {it}: no value produced",
+                    backend=backend))
+            elif bad_any[b]:
+                r, it = np.argwhere(bad[b])[0]
+                out.append(SimVerdict(
+                    False,
+                    f"node {cs.node_ids[r]} iter {it}: got {val[b, r, it]}, "
+                    f"want {cs.ref[r, it]}", backend=backend))
+            else:
+                out.append(SimVerdict(
+                    True, backend=backend,
+                    values_thunk=_values_thunk(
+                        val[b, :n, :], done[b, :n, :], cs.node_ids)))
+        return out
 
 
 def _scalar_fallback(mapping, iterations: int) -> SimVerdict:
@@ -278,40 +321,45 @@ def simulate_batch(mappings, iterations: int = 4, backend: str = "auto",
     """Batched cycle-accurate verification (see module docstring).
 
     Returns a :class:`BatchResult` — one :class:`SimVerdict` per input
-    mapping, in input order, plus throughput metadata.  Never raises on a
-    *failing mapping* (that is a ``False`` verdict); raises on backend /
-    environment faults (``OSError`` from fault injection, jax runtime
-    errors, a failing Pallas kernel).
+    mapping, in input order, plus throughput metadata and the call's
+    spans and counters.  Never raises on a *failing mapping* (that is a
+    ``False`` verdict); raises on backend / environment faults
+    (``OSError`` from fault injection, jax runtime errors, a failing
+    Pallas kernel).
 
     Pass ``prepared`` (from :func:`prepare_batch` over the *same*
     mappings/iterations) to skip the lowering + packing half and rerun
     only the vectorized backend."""
-    t0 = time.perf_counter()
-    backend = select_backend(backend)
-    faultinject.check("sim.batch", f"batch={len(mappings)}")
-    tol = tol if tol is not None else tolerance_for(backend)
+    with record() as rec, rec.span(ROOT_SPAN):
+        backend = select_backend(backend)
+        faultinject.check("sim.batch", f"batch={len(mappings)}")
+        tol = tol if tol is not None else tolerance_for(backend)
 
-    if prepared is None:
-        prepared = prepare_batch(mappings, iterations=iterations)
-    elif (prepared.n_mappings != len(mappings)
-          or prepared.iterations != iterations):
-        raise ValueError(
-            f"prepared batch is for {prepared.n_mappings} mappings x "
-            f"{prepared.iterations} iterations, got {len(mappings)} x "
-            f"{iterations}")
+        if prepared is None:
+            with span("sim.prepare"):
+                prepared = prepare_batch(mappings, iterations=iterations)
+        elif (prepared.n_mappings != len(mappings)
+              or prepared.iterations != iterations):
+            raise ValueError(
+                f"prepared batch is for {prepared.n_mappings} mappings x "
+                f"{prepared.iterations} iterations, got {len(mappings)} x "
+                f"{iterations}")
 
-    out = BatchResult([None] * len(mappings))
-    out.backend = backend
-    for i in prepared.scalar_idx:
-        out[i] = _scalar_fallback(mappings[i], iterations)
-    out.n_scalar_fallback = len(prepared.scalar_idx)
-    if prepared.packed is not None:
-        verdicts = _bucket_verdicts(
-            prepared.forms, prepared.packed, backend, tol)
-        for i, v in zip(prepared.batch_idx, verdicts):
-            out[i] = v
-        out.n_buckets = 1
-    out.wall_s = time.perf_counter() - t0
+        out = BatchResult([None] * len(mappings))
+        out.backend = backend
+        if prepared.scalar_idx:
+            with span("sim.scalar_fallback"):
+                for i in prepared.scalar_idx:
+                    out[i] = _scalar_fallback(mappings[i], iterations)
+        out.n_scalar_fallback = len(prepared.scalar_idx)
+        if prepared.packed is not None:
+            verdicts = _bucket_verdicts(
+                prepared.forms, prepared.packed, backend, tol)
+            for i, v in zip(prepared.batch_idx, verdicts):
+                out[i] = v
+            out.n_buckets = 1
+    out.spans = rec.spans
+    out.counters.update(rec.counters)
     return out
 
 

@@ -52,6 +52,13 @@ Backends:
 * ``pallas`` — the jnp backend with the ALU apply stage running as a
   Pallas kernel (``repro.kernels.sim_alu``); a kernel failure raises.
 
+Names on the device: the jitted loop runs under ``jax.named_scope``
+``sim_cycle_loop``, and each simulated cycle under ``execute`` (with
+``operand_read``, ``presence``, ``alu`` and ``value_write`` inside it) and
+``commit`` — the two phases above — so every op of the compiled program
+names its phase in its ``op_name`` metadata.  The Pallas ALU kernel is
+``sim_alu``.
+
 Final comparison against the ``ref`` oracle lives in ``repro.sim.batch``
 (it is tolerance-policy dependent; see ``repro.sim.check``).
 """
@@ -64,6 +71,7 @@ from typing import Dict, Tuple
 import numpy as np
 
 from repro.sim.lower import K_BROKEN, K_FEED, K_ROUTED, OPS
+from repro.sim.spans import count, span
 
 #: step_abs padding: far enough out that no in-horizon cycle matches
 NEVER = 1 << 30
@@ -405,46 +413,58 @@ def _jit_runner(hmax: int, iterations: int, shape: Tuple[int, ...],
 
         def body(t, carry):
             val, done, avail, fail = carry
-            act = exec_mask & (issue <= t) & (t < horB)
-            d = t - issue
-            q = d // iiB
-            act = act & (d - q * iiB == 0) & (q < I)
-            itq = jnp.where(act, q, 0)
-            want = itq[:, :, None] - op_dist
-            needs = want >= 0
-            in_range = needs & (want < I)
-            wc = jnp.clip(want, 0, I - 1)
-            vr = jnp.take(val, src_base + wc)
-            present = jnp.any(
-                jnp.take(avail, step_read_base + wc[:, :, :, None]), axis=3)
-            actk = act[:, :, None]
-            fail = fail | jnp.any(
-                actk & routed & needs & ~(present & in_range), axis=(1, 2))
-            fail = fail | jnp.any(actk & broken & needs, axis=(1, 2))
-            opv = jnp.where(routed & in_range, vr, 0.0)
-            opv = jnp.where(feed, op_feed + itq[:, :, None].astype(leaf.dtype),
-                            opv)
-            newv = alu(opcode, opv[:, :, 0], opv[:, :, 1], opv[:, :, 2],
-                       leaf + itq.astype(leaf.dtype))
-            idx = jnp.where(act, node_base + itq, dump)
-            val = val.at[idx.ravel()].set(newv.ravel())
-            done = done.at[idx.ravel()].set(True)
+            # phase 1: execute every node whose issue slot is this cycle
+            with jax.named_scope("execute"):
+                act = exec_mask & (issue <= t) & (t < horB)
+                d = t - issue
+                q = d // iiB
+                act = act & (d - q * iiB == 0) & (q < I)
+                itq = jnp.where(act, q, 0)
+                want = itq[:, :, None] - op_dist
+                needs = want >= 0
+                in_range = needs & (want < I)
+                wc = jnp.clip(want, 0, I - 1)
+                with jax.named_scope("operand_read"):
+                    vr = jnp.take(val, src_base + wc)
+                    opv = jnp.where(routed & in_range, vr, 0.0)
+                    opv = jnp.where(
+                        feed, op_feed + itq[:, :, None].astype(leaf.dtype),
+                        opv)
+                with jax.named_scope("presence"):
+                    present = jnp.any(jnp.take(
+                        avail, step_read_base + wc[:, :, :, None]), axis=3)
+                    actk = act[:, :, None]
+                    fail = fail | jnp.any(
+                        actk & routed & needs & ~(present & in_range),
+                        axis=(1, 2))
+                    fail = fail | jnp.any(actk & broken & needs, axis=(1, 2))
+                with jax.named_scope("alu"):
+                    newv = alu(opcode, opv[:, :, 0], opv[:, :, 1],
+                               opv[:, :, 2], leaf + itq.astype(leaf.dtype))
+                with jax.named_scope("value_write"):
+                    idx = jnp.where(act, node_base + itq, dump)
+                    val = val.at[idx.ravel()].set(newv.ravel())
+                    done = done.at[idx.ravel()].set(True)
 
-            kd = (t + 1) - step_abs
-            kq = kd // iiB
-            wok = (kd - kq * iiB == 0) & (kq >= 0) & (kq < I) & (t < horB)
-            kqc = jnp.where(wok, kq, 0)
-            fire = wok & jnp.take(done, wsrc_base + kqc)
-            widx = jnp.where(fire, wstep_base + kqc, wdump)
-            avail = avail.at[widx.ravel()].set(True)
+            # phase 2: commit route-step writes readable at cycle t + 1
+            with jax.named_scope("commit"):
+                kd = (t + 1) - step_abs
+                kq = kd // iiB
+                wok = ((kd - kq * iiB == 0) & (kq >= 0) & (kq < I)
+                       & (t < horB))
+                kqc = jnp.where(wok, kq, 0)
+                fire = wok & jnp.take(done, wsrc_base + kqc)
+                widx = jnp.where(fire, wstep_base + kqc, wdump)
+                avail = avail.at[widx.ravel()].set(True)
             return val, done, avail, fail
 
         val0 = jnp.zeros(B * (N + 2) * I, dtype=jnp.float32)
         done0 = jnp.zeros(B * (N + 2) * I, dtype=bool)
         avail0 = jnp.zeros(B * (S + 2) * I, dtype=bool)
         fail0 = jnp.zeros(B, dtype=bool)
-        val, done, avail, fail = jax.lax.fori_loop(
-            0, hmax, body, (val0, done0, avail0, fail0))
+        with jax.named_scope("sim_cycle_loop"):
+            val, done, avail, fail = jax.lax.fori_loop(
+                0, hmax, body, (val0, done0, avail0, fail0))
         val = val.reshape(B, N + 2, I)[:, :N, :]
         done = done.reshape(B, N + 2, I)[:, :N, :]
         return val, done, fail
@@ -472,16 +492,33 @@ def run_bucket_jnp(pb: PackedBucket, use_pallas: bool = False):
     """jnp backend: same contract as :func:`run_bucket_numpy` (values are
     float32 upcast to float64 — compare under ``F32_TOL``).  With
     ``use_pallas`` the ALU apply stage runs as a Pallas kernel; a failure
-    there raises like any other backend fault."""
+    there raises like any other backend fault.
+
+    Records spans ``sim.upload`` (until the arguments are on the device),
+    ``sim.cycle_loop`` (until the loop's outputs are ready) and
+    ``sim.pullback``, and counters ``upload_bytes``, ``pullback_bytes``
+    and ``runner_builds`` (see ``repro.sim.spans``)."""
+    import jax
+
+    misses = _jit_runner.cache_info().misses
     runner = _jit_runner(pb.hmax, pb.iterations, pb.shape, use_pallas)
-    val, done, fail = runner(*device_args(pb))
-    return (np.asarray(val, dtype=np.float64), np.asarray(done),
-            np.asarray(fail))
+    count("runner_builds", _jit_runner.cache_info().misses - misses)
+    with span("sim.upload"):
+        args = jax.block_until_ready(device_args(pb))
+    count("upload_bytes", sum(a.nbytes for a in args))
+    with span("sim.cycle_loop"):
+        out = jax.block_until_ready(runner(*args))
+    count("pullback_bytes", sum(a.nbytes for a in out))
+    with span("sim.pullback"):
+        val, done, fail = out
+        return (np.asarray(val, dtype=np.float64), np.asarray(done),
+                np.asarray(fail))
 
 
 def run_bucket(pb: PackedBucket, backend: str):
     if backend == "numpy":
-        return run_bucket_numpy(pb)
+        with span("sim.cycle_loop"):
+            return run_bucket_numpy(pb)
     if backend == "jnp":
         return run_bucket_jnp(pb, use_pallas=False)
     if backend == "pallas":
