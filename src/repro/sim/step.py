@@ -1,31 +1,22 @@
 """Vectorized whole-grid step function over packed ``CompiledSim`` buckets.
 
-One call executes every cycle of every mapping in a bucket — state is four
-dense tensors instead of the scalar oracle's dicts:
+One call executes every cycle of every mapping in a bucket; the scalar
+oracle's dicts become dense tensors:
 
-* ``val[b, node, iter]``  — produced values (``+2`` sentinel rows: a read
+* ``val[b, node, iter]`` — produced values (``+2`` sentinel rows: a read
   sentinel that stays 0.0 for absent operands, and a write dump that soaks
   up masked-out scatters on backends without boolean scatter).
-* ``done[b, node, iter]`` — which (node, iteration) values exist yet.
-* ``avail[b, step, iter]`` — which route-step reservations hold a readable
-  value; a routed operand read is *present* iff any of its matched steps
-  is available (the tensor form of the oracle's ``(rid, net, iter)`` key).
-* ``fail[b]``             — sticky per-mapping read failure (missing
-  operand / unrouted-edge read), exactly where the scalar oracle asserts.
+* ``done[b, node, iter]`` — which (node, iteration) values exist.
+* ``fail[b]`` — per-mapping read failure (missing operand / unrouted-edge
+  read), exactly where the scalar oracle asserts.
 
-Per cycle ``t``:  phase 1 executes every node whose issue slot matches
-(``(t - issue) % ii == 0``), gathering operands (reads see state as of the
-*start* of the cycle); phase 2 commits route-step writes that become
-readable at cycle ``t + 1``, gated on the producer's value existing —
-bit-for-bit the scalar oracle's two-phase loop, vectorized over
-batch × nodes × steps.
-
-The numpy backend exploits a further invariant: batched execution never
-*gates* an FU on operand presence (a missing read sets ``fail`` and the
-node computes with a 0.0 operand, exactly mirroring where the scalar
-oracle would assert).  Node ``n`` therefore produces iteration ``k`` iff
-``issue + k*ii < horizon`` — ``done`` is a pure timing function — and a
-route step's availability unrolls to a *static* predicate::
+Batched execution never *gates* an FU on operand presence: a missing read
+sets ``fail`` and the node computes with a 0.0 operand, exactly mirroring
+where the scalar oracle would assert.  Node ``n`` therefore produces
+iteration ``k`` iff ``issue + k*ii < horizon`` — ``done`` is a pure timing
+function — and the oracle's route-step availability (its commit phase:
+a step becomes readable one cycle after its write, if the producer's value
+exists) unrolls to a *static* predicate::
 
     avail(step, k) ⇔ exec(src) ∧ issue_src < step_abs          (producer
                      committed before the write cycle step_abs + k·ii − 1)
@@ -34,30 +25,31 @@ route step's availability unrolls to a *static* predicate::
                      ∧ avail(step)          (iteration-independent: both
                      read and arrival cycles shift by the same k·ii)
 
-so every read-failure check hoists out of the cycle loop entirely; the
-loop that remains only propagates *values* (the data recurrence still
-needs ordered evaluation).  The jnp backend keeps the explicit dynamic
-``avail`` state machine — one traced program per bucket shape — so the
-two backends cross-check each other's semantics in the differential
-tests.
+so every read-failure check hoists out of the cycle loop.  Both backends
+compute these predicates with the same two functions over the array
+module, :func:`step_arrival` and :func:`read_checks` (``numpy`` on the
+host, ``jax.numpy`` inside the jitted program, on every call).  The cycle
+loop that remains only propagates *values*: the data recurrence still
+needs ordered evaluation, and each cycle's operand reads see the values as
+of the start of the cycle.
 
 Backends:
 
-* ``numpy``  — float64 reference; static-availability fast path, fastest
-  on CPU-only hosts and verdict/value-identical to the scalar oracle
-  under ``DEFAULT_TOL``.
-* ``jnp``    — float32, ``lax.fori_loop`` under ``jit``; the dynamic
-  two-phase state machine traced once per bucket shape, for accelerator
-  execution.
+* ``numpy``  — float64 reference; the loop runs as a precomputed event
+  schedule, fastest on CPU-only hosts and verdict/value-identical to the
+  scalar oracle under ``DEFAULT_TOL``.
+* ``jnp``    — float32, a value-only ``lax.fori_loop`` under ``jit``, one
+  traced program per bucket shape, for accelerator execution.
 * ``pallas`` — the jnp backend with the ALU apply stage running as a
   Pallas kernel (``repro.kernels.sim_alu``); a kernel failure raises.
 
-Names on the device: the jitted loop runs under ``jax.named_scope``
-``sim_cycle_loop``, and each simulated cycle under ``execute`` (with
-``operand_read``, ``presence``, ``alu`` and ``value_write`` inside it) and
-``commit`` — the two phases above — so every op of the compiled program
-names its phase in its ``op_name`` metadata.  The Pallas ALU kernel is
-``sim_alu``.
+Names on the device: the jitted program runs under ``jax.named_scope``
+``sim_cycle_loop``.  Before the loop, the static predicates run under
+``commit`` (:func:`step_arrival`, the static form of the oracle's commit
+phase) and ``execute/presence`` (:func:`read_checks`); each simulated
+cycle runs under ``execute``, with ``operand_read``, ``alu`` and
+``value_write`` inside it, so every op of the compiled program names its
+phase in its ``op_name`` metadata.  The Pallas ALU kernel is ``sim_alu``.
 
 Final comparison against the ``ref`` oracle lives in ``repro.sim.batch``
 (it is tolerance-policy dependent; see ``repro.sim.check``).
@@ -166,51 +158,61 @@ class PackedBucket:
         return b, n, k, m, self.step_src.shape[1]
 
 
+# -- static availability (both backends) ------------------------------------
+
+
+def step_arrival(xp, exec_mask, issue, step_src, step_abs):
+    """(B,S+1) cycle from which each route step holds a readable value
+    (iteration k's from that cycle + k·ii), or ``NEVER``: a step holds its
+    values iff its producer committed before the write cycle, ``exec(src)
+    ∧ issue_src < step_abs`` (the static form of the oracle's commit
+    phase).  Sentinel row N is never
+    exec, padded steps carry ``NEVER``, and the appended column S is the
+    never-available sentinel step.  ``xp`` is ``numpy`` or ``jax.numpy``."""
+    B = exec_mask.shape[0]
+    never = xp.full((B, 1), NEVER, dtype=xp.int32)
+    src_issue = xp.take_along_axis(
+        xp.concatenate([xp.where(exec_mask, issue, never), never], axis=1),
+        step_src, axis=1)                                        # (B,S)
+    return xp.concatenate(
+        [xp.where(src_issue < step_abs, step_abs, never), never], axis=1)
+
+
+def read_checks(xp, iterations, ii, horizon, exec_mask, issue, op_kind,
+                op_dist, op_steps, arrival):
+    """``done`` (B,N,I) — a pure timing function — and ``fail`` (B,) —
+    every read-failure check of the cycle loop, hoisted out of it
+    (derivation in the module docstring); ``arrival`` is
+    :func:`step_arrival`'s table."""
+    B, N, K, M = op_steps.shape
+    ii3 = ii[:, None, None]
+    hor3 = horizon[:, None, None]
+    it_r = xp.arange(iterations, dtype=xp.int32)
+    done = exec_mask[:, :, None] & (issue[:, :, None] + it_r * ii3 < hor3)
+    arr = xp.take_along_axis(arrival, op_steps.reshape(B, N * K * M),
+                             axis=1).reshape(B, N, K, M)
+    # presence is iteration-independent: arrival step_abs + (it-dist)*ii
+    # <= read cycle issue_dst + it*ii  ⇔  step_abs <= issue_dst + dist*ii
+    deadline = issue[:, :, None] + op_dist * ii3                 # (B,N,K)
+    ok_col = (arr <= deadline[:, :, :, None]).any(axis=3)
+    # the first needy read is iteration `dist`; it happens iff that
+    # execution lands inside the horizon (deadline is exactly its cycle)
+    reads = exec_mask[:, :, None] & (op_dist < iterations) & (deadline < hor3)
+    bad = (op_kind == K_BROKEN) | ((op_kind == K_ROUTED) & ~ok_col)
+    return done, (reads & bad).any(axis=(1, 2))
+
+
 # -- numpy backend -----------------------------------------------------------
 
 
 def _np_static(pb: PackedBucket):
-    """One-time static predicates (derivation in the module docstring):
-    ``done`` (B,N,I) — a pure timing function — and ``fail`` (B,) — every
-    read-failure check hoisted out of the cycle loop."""
-    B, N, K, M, S = pb.shape
-    I = pb.iterations
-    ii3 = pb.ii[:, None, None]
-    hor3 = pb.horizon[:, None, None]
-    routed = pb.op_kind == K_ROUTED
-    broken = pb.op_kind == K_BROKEN
-
-    it_r = np.arange(I, dtype=np.int32)
-    done = pb.exec_mask[:, :, None] & (
-        pb.issue[:, :, None] + it_r * ii3 < hor3)                # (B,N,I)
-
-    b2 = np.arange(B)[:, None]
-    exec_pad = np.concatenate(
-        [pb.exec_mask, np.zeros((B, 1), dtype=bool)], axis=1)    # (B,N+1)
-    issue_pad = np.concatenate(
-        [pb.issue, np.zeros((B, 1), dtype=np.int32)], axis=1)
-    # a step holds iteration k's value iff its producer committed before
-    # the write cycle: exec(src) and issue_src < step_abs (sentinel row N
-    # is never exec; padded steps carry step_abs = NEVER)
-    step_ok = (exec_pad[b2, pb.step_src]
-               & (issue_pad[b2, pb.step_src] < pb.step_abs))     # (B,S)
-    sa_pad = np.concatenate(
-        [pb.step_abs, np.full((B, 1), NEVER, dtype=np.int32)], axis=1)
-    so_pad = np.concatenate(
-        [step_ok, np.zeros((B, 1), dtype=bool)], axis=1)
-    b4 = np.arange(B)[:, None, None, None]
-    sa = sa_pad[b4, pb.op_steps]                                 # (B,N,K,M)
-    so = so_pad[b4, pb.op_steps]
-    # presence is iteration-independent: arrival step_abs + (it-dist)*ii
-    # <= read cycle issue_dst + it*ii  ⇔  step_abs <= issue_dst + dist*ii
-    deadline = pb.issue[:, :, None] + pb.op_dist * ii3           # (B,N,K)
-    ok_col = ((sa <= deadline[:, :, :, None]) & so).any(axis=3)
-    # the first needy read is iteration `dist`; it happens iff that
-    # execution lands inside the horizon (deadline is exactly its cycle)
-    reads = (pb.exec_mask[:, :, None] & (pb.op_dist < I)
-             & (deadline < hor3))
-    fail = (reads & (broken | (routed & ~ok_col))).any(axis=(1, 2))
-    return done, fail
+    """The static predicates of one bucket on the host (memoized on
+    ``pb.cache`` by :func:`run_bucket_numpy`)."""
+    arrival = step_arrival(np, pb.exec_mask, pb.issue, pb.step_src,
+                           pb.step_abs)
+    return read_checks(np, pb.iterations, pb.ii, pb.horizon, pb.exec_mask,
+                       pb.issue, pb.op_kind, pb.op_dist, pb.op_steps,
+                       arrival)
 
 
 def _np_schedule(pb: PackedBucket):
@@ -381,7 +383,7 @@ def _jit_runner(hmax: int, iterations: int, shape: Tuple[int, ...],
     import jax
     import jax.numpy as jnp
 
-    B, N, K, M, S = shape
+    B, N = shape[:2]
     I = iterations
 
     if use_pallas:
@@ -401,19 +403,10 @@ def _jit_runner(hmax: int, iterations: int, shape: Tuple[int, ...],
                      + jnp.arange(N)[None, :]) * I
         dump = jnp.int32((B * (N + 2) - 1) * I)  # last dump row, iter 0
         src_base = (jnp.arange(B)[:, None, None] * (N + 2) + op_src) * I
-        step_read_base = (jnp.arange(B)[:, None, None, None] * (S + 2)
-                          + op_steps) * I
-        wsrc_base = (jnp.arange(B)[:, None] * (N + 2) + step_src) * I
-        wstep_base = (jnp.arange(B)[:, None] * (S + 2)
-                      + jnp.arange(S)[None, :]) * I
-        wdump = jnp.int32((B * (S + 2) - 1) * I)
         routed = op_kind == K_ROUTED
-        broken = op_kind == K_BROKEN
         feed = op_kind == K_FEED
 
-        def body(t, carry):
-            val, done, avail, fail = carry
-            # phase 1: execute every node whose issue slot is this cycle
+        def body(t, val):
             with jax.named_scope("execute"):
                 act = exec_mask & (issue <= t) & (t < horB)
                 d = t - issue
@@ -421,8 +414,7 @@ def _jit_runner(hmax: int, iterations: int, shape: Tuple[int, ...],
                 act = act & (d - q * iiB == 0) & (q < I)
                 itq = jnp.where(act, q, 0)
                 want = itq[:, :, None] - op_dist
-                needs = want >= 0
-                in_range = needs & (want < I)
+                in_range = (want >= 0) & (want < I)
                 wc = jnp.clip(want, 0, I - 1)
                 with jax.named_scope("operand_read"):
                     vr = jnp.take(val, src_base + wc)
@@ -430,44 +422,24 @@ def _jit_runner(hmax: int, iterations: int, shape: Tuple[int, ...],
                     opv = jnp.where(
                         feed, op_feed + itq[:, :, None].astype(leaf.dtype),
                         opv)
-                with jax.named_scope("presence"):
-                    present = jnp.any(jnp.take(
-                        avail, step_read_base + wc[:, :, :, None]), axis=3)
-                    actk = act[:, :, None]
-                    fail = fail | jnp.any(
-                        actk & routed & needs & ~(present & in_range),
-                        axis=(1, 2))
-                    fail = fail | jnp.any(actk & broken & needs, axis=(1, 2))
                 with jax.named_scope("alu"):
                     newv = alu(opcode, opv[:, :, 0], opv[:, :, 1],
                                opv[:, :, 2], leaf + itq.astype(leaf.dtype))
                 with jax.named_scope("value_write"):
                     idx = jnp.where(act, node_base + itq, dump)
-                    val = val.at[idx.ravel()].set(newv.ravel())
-                    done = done.at[idx.ravel()].set(True)
+                    return val.at[idx.ravel()].set(newv.ravel())
 
-            # phase 2: commit route-step writes readable at cycle t + 1
-            with jax.named_scope("commit"):
-                kd = (t + 1) - step_abs
-                kq = kd // iiB
-                wok = ((kd - kq * iiB == 0) & (kq >= 0) & (kq < I)
-                       & (t < horB))
-                kqc = jnp.where(wok, kq, 0)
-                fire = wok & jnp.take(done, wsrc_base + kqc)
-                widx = jnp.where(fire, wstep_base + kqc, wdump)
-                avail = avail.at[widx.ravel()].set(True)
-            return val, done, avail, fail
-
-        val0 = jnp.zeros(B * (N + 2) * I, dtype=jnp.float32)
-        done0 = jnp.zeros(B * (N + 2) * I, dtype=bool)
-        avail0 = jnp.zeros(B * (S + 2) * I, dtype=bool)
-        fail0 = jnp.zeros(B, dtype=bool)
         with jax.named_scope("sim_cycle_loop"):
-            val, done, avail, fail = jax.lax.fori_loop(
-                0, hmax, body, (val0, done0, avail0, fail0))
-        val = val.reshape(B, N + 2, I)[:, :N, :]
-        done = done.reshape(B, N + 2, I)[:, :N, :]
-        return val, done, fail
+            with jax.named_scope("commit"):
+                arrival = step_arrival(jnp, exec_mask, issue, step_src,
+                                       step_abs)
+            with jax.named_scope("execute"), jax.named_scope("presence"):
+                done, fail = read_checks(
+                    jnp, I, ii, horizon, exec_mask, issue, op_kind,
+                    op_dist, op_steps, arrival)
+            val = jax.lax.fori_loop(
+                0, hmax, body, jnp.zeros(B * (N + 2) * I, dtype=jnp.float32))
+        return val.reshape(B, N + 2, I)[:, :N, :], done, fail
 
     return jax.jit(run)
 

@@ -181,7 +181,8 @@ def test_fuzz_random_dag_parity(seed, n):
     assert_differential([m], iterations=3)
 
 
-def test_corrupted_mappings_fail_identically(mappings):
+@pytest.mark.parametrize("backend", ["numpy", "jnp"])
+def test_corrupted_mappings_fail_identically(mappings, backend):
     good = mappings[0]
 
     dropped = copy.deepcopy(good)
@@ -197,11 +198,73 @@ def test_corrupted_mappings_fail_identically(mappings):
     # parity is the assertion: each corrupted form must get the SAME
     # verdict from both engines (assert_differential raises on divergence)
     batch = [good, dropped, foreign, shifted]
-    assert_differential(batch, iterations=3)
-    res = simulate_batch(batch, iterations=3)
+    assert_differential(batch, iterations=3, backend=backend)
+    res = simulate_batch(batch, iterations=3, backend=backend)
+    assert res.backend == backend
     assert res[0].ok
     assert not res[1].ok and "not present at read time" in res[1].reason
     assert not res[2].ok and "unknown node 99999" in res[2].reason
+
+
+# One producer (row 0, issue ``src``) feeds operand 0 of one consumer (row
+# 1, issue ``dst``) through one route step (``step_abs``) at distance
+# ``dist``; ii 2, horizon ``hor``, 3 iterations.  Written by hand: the
+# read of iteration ``it`` at cycle dst + it*ii wants the value that
+# arrives at step_abs + (it - dist)*ii, which exists iff src < step_abs.
+EDGES = {
+    # name: (src, step_abs, dst, dist, kind, hor, fail, consumer done)
+    "arrival_at_read_cycle": (0, 3, 3, 0, "routed", 12, False, [1, 1, 1]),
+    "arrival_one_cycle_late": (0, 4, 3, 0, "routed", 12, True, [1, 1, 1]),
+    "producer_issued_at_step_abs": (3, 3, 5, 0, "routed", 12, True,
+                                    [1, 1, 1]),
+    "first_needy_read_at_horizon": (0, 13, 10, 1, "routed", 12, False,
+                                    [1, 0, 0]),
+    "first_needy_read_before_horizon": (0, 13, 10, 1, "routed", 13, True,
+                                        [1, 1, 0]),
+    "dist_at_least_iterations": (0, 10, 3, 3, "routed", 12, False,
+                                 [1, 1, 1]),
+    "broken_column": (0, 3, 3, 0, "broken", 12, True, [1, 1, 1]),
+}
+
+
+def _edge_bucket(src, step_abs, dst, dist, kind, hor):
+    from repro.sim.lower import K_ABSENT, K_BROKEN, K_ROUTED, OP_INDEX
+    from repro.sim.step import PackedBucket
+
+    N, K, M, S, I = 2, 3, 1, 1, 3
+    op_kind = np.full((1, N, K), K_ABSENT, dtype=np.int8)
+    op_kind[0, 1, 0] = K_ROUTED if kind == "routed" else K_BROKEN
+    op_src = np.full((1, N, K), N, dtype=np.int32)
+    op_src[0, 1, 0] = 0
+    op_dist = np.zeros((1, N, K), dtype=np.int32)
+    op_dist[0, 1, 0] = dist
+    op_steps = np.full((1, N, K, M), S, dtype=np.int32)
+    op_steps[0, 1, 0, 0] = 0
+    return PackedBucket(
+        iterations=I, hmax=hor, ii=np.array([2], dtype=np.int32),
+        horizon=np.array([hor], dtype=np.int32),
+        opcode=np.array([[OP_INDEX["const"], OP_INDEX["store"]]],
+                        dtype=np.int32),
+        exec_mask=np.ones((1, N), dtype=bool),
+        issue=np.array([[src, dst]], dtype=np.int32),
+        compare=np.zeros((1, N), dtype=bool), leaf=np.zeros((1, N)),
+        ref=np.zeros((1, N, I)), op_kind=op_kind, op_src=op_src,
+        op_dist=op_dist, op_feed=np.zeros((1, N, K)), op_steps=op_steps,
+        step_src=np.zeros((1, S), dtype=np.int32),
+        step_abs=np.array([[step_abs]], dtype=np.int32))
+
+
+@pytest.mark.parametrize("backend", ["numpy", "jnp"])
+@pytest.mark.parametrize("edge", sorted(EDGES))
+def test_static_availability_edges(edge, backend):
+    """``fail`` and ``done`` of hand-packed buckets at the edges of the
+    static availability derivation, on the host and the device backend."""
+    src, step_abs, dst, dist, kind, hor, fail, consumer = EDGES[edge]
+    pb = _edge_bucket(src, step_abs, dst, dist, kind, hor)
+    _, done, got_fail = step.run_bucket(pb, backend)
+    assert got_fail.tolist() == [fail]
+    producer = [src + it * 2 < hor for it in range(3)]
+    assert done.tolist() == [[producer, [bool(d) for d in consumer]]]
 
 
 def test_verify_mappings_raises_on_disproof(mappings):

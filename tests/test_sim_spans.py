@@ -99,8 +99,8 @@ def test_scalar_fallback_span_exactly_when_a_mapping_falls_back(mappings):
         assert ("sim.scalar_fallback" in names) == (fallbacks > 0)
 
 
-def _while_body(hlo: str):
-    """The instructions of the cycle loop's ``while`` body computation."""
+def _computations(hlo: str):
+    """``name -> instruction lines`` of every computation in ``hlo``."""
     comps, cur = {}, None
     for line in hlo.splitlines():
         head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) .*\{$", line)
@@ -110,34 +110,68 @@ def _while_body(hlo: str):
             cur = None
         elif cur is not None:
             cur.append(line)
+    return comps
+
+
+def _while_body(hlo: str):
+    """The instructions of the cycle loop's ``while`` body computation and
+    of every computation it calls (fusions, scatter combiners)."""
+    comps = _computations(hlo)
     loops = [line for body in comps.values() for line in body
              if " while(" in line]
     assert len(loops) == 1, loops
-    return comps[re.search(r"body=%?([\w.\-]+)", loops[0]).group(1)]
+    todo = [re.search(r"body=%?([\w.\-]+)", loops[0]).group(1)]
+    seen, lines = set(), []
+    while todo:
+        name = todo.pop()
+        if name in seen:
+            continue
+        seen.add(name)
+        lines += comps[name]
+        for line in comps[name]:
+            todo += re.findall(r"(?:calls|to_apply)=%?([\w.\-]+)", line)
+    return lines
+
+
+def _op_name(line: str):
+    m = re.search(r'op_name="([^"]*)"', line)
+    return m and m.group(1)
 
 
 def test_cycle_loop_ops_carry_their_phase_scope(mappings):
+    """The ``while`` body propagates values only: its ops sit under
+    ``execute`` (``operand_read``, ``alu``, ``value_write``), none under
+    ``presence`` or ``commit``; the static availability predicates run
+    once per call before the loop, under ``sim_cycle_loop/commit`` and
+    ``sim_cycle_loop/execute/presence``."""
     pb = prepare_batch(mappings, iterations=3).packed
     runner = step._jit_runner(pb.hmax, pb.iterations, pb.shape, False)
     hlo = runner.lower(*step.device_args(pb)).compile().as_text()
+    body = _while_body(hlo)
     scoped, counter = [], []
-    for line in _while_body(hlo):
+    for line in body:
         m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = .*? (gather|scatter|fusion)"
                      r"\(", line)
         if not m:
             continue
-        op_name = re.search(r'op_name="([^"]*)"', line).group(1)
+        op_name = _op_name(line)
         assert "/sim_cycle_loop/" in op_name, line
         if op_name.endswith("/while/body/add"):   # the loop's own counter
             counter.append(m.group(1))
         else:
             scoped.append(op_name)
-            assert re.search(r"/sim_cycle_loop/.*/(execute|commit)/",
-                             op_name), line
+            assert re.search(r"/sim_cycle_loop/.*/execute/", op_name), line
+            if m.group(2) == "gather":
+                assert "/execute/operand_read/" in op_name, line
     assert len(counter) <= 1 and scoped
-    assert any("/commit/" in n for n in scoped)
-    for step_name in ("operand_read", "presence", "alu", "value_write"):
-        assert f"/execute/{step_name}/" in hlo, step_name
+    names = {_op_name(line) for line in body} - {None}
+    assert not [n for n in names if "/presence/" in n or "/commit/" in n]
+    for step_name in ("operand_read", "alu", "value_write"):
+        assert any(f"/execute/{step_name}/" in n for n in names), step_name
+    hoisted = {_op_name(line) for line in hlo.splitlines()} - names - {None}
+    for scope in ("sim_cycle_loop/commit/",
+                  "sim_cycle_loop/execute/presence/"):
+        assert any(scope in n and "/while/" not in n for n in hoisted), scope
 
 
 def _profile_start_and_annotations(trace_dir):
