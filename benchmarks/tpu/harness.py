@@ -3,9 +3,12 @@ compile clock, the peaks table and the result line.
 
 A cell ``<cell>`` of ``BENCHMARK.json`` is the file ``cells/<cell>.json``;
 it names its configuration (``configs/<config>.json`` with its builder
-``configs/<config>.py``) and its traffic kind (``traffic/<kind>.py``).  A
-per-layer metric ``<metric>`` is read by ``layer_metrics/<metric>.py``.
-Adding any of them is adding files and ``BENCHMARK.json`` entries.
+``configs/<config>.py``), its traffic mix and the limits of its checks.
+A mix ``<traffic>`` is the data file ``traffic/<traffic>.json``: the
+parameters of one general generator, which it names
+(``traffic/<generator>.py``).  A per-layer metric ``<metric>`` is read by
+``layer_metrics/<metric>.py``.  Adding any of them is adding files and
+``BENCHMARK.json`` entries.
 """
 from __future__ import annotations
 
@@ -50,10 +53,10 @@ class Cell:
 
     name: str
     entry: Dict                   # the BENCHMARK.json workload entry
-    params: Dict                  # cells/<cell>.json
+    params: Dict                  # traffic/<traffic>.json, cells/<cell>.json
     config: Dict                  # configs/<config>.json
     config_module: ModuleType     # configs/<config>.py
-    traffic: ModuleType           # traffic/<kind>.py
+    traffic: ModuleType           # traffic/<generator>.py
     end_to_end: List[Dict] = field(default_factory=list)
     per_layer: List[Dict] = field(default_factory=list)
 
@@ -74,11 +77,16 @@ def find_cell(name: str, bench: Optional[Dict] = None,
         raise KeyError(f"no workload {name!r} in BENCHMARK.json "
                        f"(have: {', '.join(sorted(entries))})")
     entry = entries[name]
-    params = read_json(here / "cells" / f"{name}.json")
-    if params["config"] != entry["config"] or params["traffic"] != entry[
-            "traffic"]:
+    own = read_json(here / "cells" / f"{name}.json")
+    if own["config"] != entry["config"] or own["traffic"] != entry["traffic"]:
         raise ValueError(f"cells/{name}.json disagrees with BENCHMARK.json "
                          "on its config or traffic")
+    mix = read_json(here / "traffic" / f"{entry['traffic']}.json")
+    shared = set(mix) & set(own) - {"why"}
+    if shared:
+        raise ValueError(f"cells/{name}.json repeats the mix's "
+                         f"{sorted(shared)}")
+    params = {**mix, **own}
     config = read_json(here / "configs" / f"{entry['config']}.json")
     e2e = [m for m in bench["end_to_end"]
            if "workloads" not in m or name in m["workloads"]]
@@ -87,7 +95,7 @@ def find_cell(name: str, bench: Optional[Dict] = None,
     return Cell(
         name=name, entry=entry, params=params, config=config,
         config_module=load_module(here / "configs" / f"{entry['config']}.py"),
-        traffic=load_module(here / "traffic" / f"{entry['traffic']}.py"),
+        traffic=load_module(here / "traffic" / f"{mix['generator']}.py"),
         end_to_end=e2e, per_layer=layer)
 
 

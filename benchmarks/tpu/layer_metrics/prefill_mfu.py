@@ -2,7 +2,7 @@
 at the cell's batch and prompt length: every matmul, causal attention,
 the output head on the last token) over their device time
 (``train/steps.make_prefill_step``, the program ``jit_prefill_step``),
-over the chip's bf16 peak.  Moves ``request_p95_ms``."""
+over the chip's bf16 peak.  Moves ``output_tokens_per_s``."""
 
 MODULE = "prefill_step"
 

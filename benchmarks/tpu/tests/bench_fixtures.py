@@ -12,16 +12,23 @@ TINY_QWEN = {
     "tie_word_embeddings": True, "vocab_size": 256,
 }
 
+#: tiny traffic mixes, each read by one of the real generators
+TINY_TRAFFIC = {
+    "verify_tiny": {"generator": "verify_sweep", "backend": "jnp",
+                    "check_values": 1000},
+    "serve_tiny": {"generator": "serve_closed", "prompt_len": 16,
+                   "new_tokens": 4},
+}
+
 TINY_CELLS = {
     "verify_sweep.tiny": {
-        "config": "table2", "traffic": "verify_sweep",
-        "backend": "jnp", "check_values": 1000,
+        "config": "table2", "traffic": "verify_tiny",
         "limits": {"verdicts_wrong": 0, "values_missing": 0,
                    "value_gap": 0.004, "answers_missing": 0}},
     "serve_decode.tiny": {
-        "config": "qwen3_tiny", "traffic": "serve_closed",
-        "clients": 2, "prompt_len": 16, "new_tokens": 4,
-        "check_requests": 2, "limits": {"served_logit_gap": 0.01}},
+        "config": "qwen3_tiny", "traffic": "serve_tiny",
+        "clients": 2, "check_requests": 2,
+        "limits": {"served_logit_gap": 0.01}},
 }
 
 #: the metrics each tiny cell reports: an entry of BENCHMARK.json of the
@@ -34,9 +41,6 @@ TINY_METRICS = {
          "workloads": ["verify_sweep.tiny"]},
         {"name": "output_tokens_per_s", "unit": "tokens/s",
          "better": "higher", "bound": 0.03, "source": "host_clock",
-         "workloads": ["serve_decode.tiny"]},
-        {"name": "request_p95_ms", "unit": "ms", "better": "lower",
-         "bound": 0.03, "source": "host_clock",
          "workloads": ["serve_decode.tiny"]},
     ],
     "per_layer": [

@@ -17,13 +17,15 @@ for p in (str(HERE), str(REPO / "src")):
     if p not in sys.path:
         sys.path.append(p)
 
-from bench_fixtures import TINY_CELLS, TINY_METRICS, TINY_QWEN  # noqa: E402
+from bench_fixtures import (  # noqa: E402
+    TINY_CELLS, TINY_METRICS, TINY_QWEN, TINY_TRAFFIC)
 
 
 @pytest.fixture
 def tiny_bench(tmp_path):
     """``(here, root)`` of a copy of the benchmark whose BENCHMARK.json
-    also lists the tiny cells; their files are added, none is edited."""
+    also lists the tiny cells; their files and mixes are added, none is
+    edited."""
     root = tmp_path / "checkout"
     here = root / "benchmarks" / "tpu"
     shutil.copytree(HERE, here, ignore=shutil.ignore_patterns(
@@ -35,6 +37,8 @@ def tiny_bench(tmp_path):
     bench["configs"].append({"name": "qwen3_tiny", "source": "test",
                              "file": "benchmarks/tpu/configs/qwen3_tiny.json",
                              "reduced": [], "why": "test"})
+    for name, mix in TINY_TRAFFIC.items():
+        (here / "traffic" / f"{name}.json").write_text(json.dumps(mix))
     for name, cell in TINY_CELLS.items():
         (here / "cells" / f"{name}.json").write_text(json.dumps(cell))
         bench["workloads"].append({"name": name, "config": cell["config"],

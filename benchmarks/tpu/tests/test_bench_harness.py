@@ -88,14 +88,17 @@ def _run(tiny_bench, cpu, monkeypatch, capsys, cell, seed=2**33 + 1,
 
 @pytest.mark.parametrize("cell,metrics", [
     ("verify_sweep.tiny", {"verify_mappings_per_s", "setup_s"}),
-    ("serve_decode.tiny", {"output_tokens_per_s", "request_p95_ms",
-                           "setup_s"}),
+    ("serve_decode.tiny", {"output_tokens_per_s", "setup_s"}),
 ])
 def test_tiny_run_is_correct(tiny_bench, cpu, monkeypatch, capsys, cell,
                              metrics):
     r = _run(tiny_bench, cpu, monkeypatch, capsys, cell)
     assert r["correct"] is True and r["failed"] == 0 and r["attempted"] > 0
     assert set(r["metrics"]) == metrics
+    here, root = tiny_bench
+    traffic = harness.find_cell(cell, here=here, root=root).traffic
+    assert set(traffic.END_TO_END) == metrics - {"setup_s"}
+    assert set(r["checks"]) == set(traffic.CHECKS)
     assert all(m["value"] > 0 for m in r["metrics"].values())
     assert r["device"]["count"] == 1
 
@@ -216,3 +219,31 @@ def test_draws_give_every_seed_the_same_bucket_shape(cpu):
         orders.add(tuple(t.order.tolist()))
         assert sorted(t.order.tolist()) == list(range(len(t.pool)))
     assert len(shapes) == 1 and len(orders) == 2
+
+
+
+@pytest.mark.parametrize("k,unanswered", [(8, ()), (3, ()), (8, (5,))])
+def test_served_sample_takes_each_request_from_a_slot_of_its_own(
+        cpu, k, unanswered):
+    """A serve cell's sample draws each request from another row of the
+    batch, the same from the same seed; a slot that never answered gives
+    none."""
+    import dataclasses
+
+    cell = harness.find_cell("serve_decode.qwen3_14b_d10")
+    cell = dataclasses.replace(cell, params={
+        **cell.params, "clients": 8, "prompt_len": 4, "new_tokens": 2,
+        "check_requests": k})
+    ctx = harness.Context(cell=cell, seed=2**33 + 5, devices=cpu, peaks={})
+    picks = []
+    for _ in range(2):
+        t = cell.traffic.Traffic(ctx)
+        for _ in range(3):
+            tokens = np.ones((8, 2), np.int32)
+            tokens[list(unanswered)] = -1
+            t.batches.append({"prompts": None, "tokens": tokens})
+        picks.append(t._sample())
+    slots = [r for _, r in picks[0]]
+    assert len(set(slots)) == len(slots) == min(k, 8 - len(unanswered))
+    assert not set(slots) & set(unanswered)
+    assert all(0 <= i < 3 for i, _ in picks[0]) and picks[0] == picks[1]

@@ -6,10 +6,9 @@ prompt is ``prompt_len`` token ids drawn from the seed and the batch
 number; every answer is ``new_tokens`` greedy tokens.  Every seed sends
 the same sizes, so only the token ids differ between seeds.
 
-A request is issued when its batch is handed to the server and done when
-its last token is on the host.  The window runs whole calls until
-``--seconds`` have passed; the rate counts every token of every request
-completed over the whole window.
+A request is done when its last token is on the host.  The window runs
+whole calls until ``--seconds`` have passed; the rate counts every token
+of every request completed over the whole window.
 
 Correct: once the window has closed and the weights are freed, the plain
 float32 reference (``reference/qwen3.py``) runs over a sample of completed
@@ -29,6 +28,9 @@ from harness import Check, Context, span
 import work
 
 SPANS = ("prompts", "generate")
+#: what ``end_to_end`` reports and what ``checks`` compares
+END_TO_END = ("output_tokens_per_s",)
+CHECKS = ("served_logit_gap",)
 
 
 class Traffic:
@@ -72,12 +74,9 @@ class Traffic:
             i = len(self.batches)
             with span("prompts"):
                 prompts = self.prompts(i)
-            issued = time.perf_counter()
             with span("generate"):
                 tokens = self._serve(prompts)
-            done = time.perf_counter()
-            self.batches.append({"prompts": prompts, "tokens": tokens,
-                                 "latency_s": done - issued})
+            self.batches.append({"prompts": prompts, "tokens": tokens})
             self.window_s = time.perf_counter() - t0
             if self.window_s >= seconds:
                 break
@@ -99,15 +98,12 @@ class Traffic:
                                     for b in self.batches)
 
     def end_to_end(self) -> Dict[str, float]:
-        """Tokens of the requests completed, over the window; the 95th
-        percentile of every request's latency (one that failed counts with
-        the latency of its call, and fails the run besides)."""
+        """Tokens of the requests completed, over the window.  No latency
+        percentile: every request of a call has the call's latency, and a
+        window holds a few calls."""
         tokens = sum(int(self._answered(b).sum()) * self.new
                      for b in self.batches)
-        lat = [b["latency_s"] * 1e3 for b in self.batches
-               for _ in range(self.B)]
-        return {"output_tokens_per_s": tokens / self.window_s,
-                "request_p95_ms": float(np.percentile(lat, 95))}
+        return {"output_tokens_per_s": tokens / self.window_s}
 
     def work(self) -> Dict:
         """Work of one ``generate`` call at this cell's sizes."""
@@ -120,13 +116,21 @@ class Traffic:
     # -- correctness ------------------------------------------------------
     def _sample(self):
         """The requests compared: ``check_requests`` of those completed,
-        drawn from the seed (every request has the same length)."""
-        done = [(i, r) for i, b in enumerate(self.batches)
-                for r in np.flatnonzero(self._answered(b))]
+        drawn from the seed, each from a slot (a row of the batch) of its
+        own, so that a fault in any one slot shows once the sample holds
+        as many requests as there are clients (every request has the same
+        length)."""
+        if not self.batches:
+            return []
         rng = np.random.default_rng([self.ctx.seed % 2**64, 2**33])
-        k = min(self.ctx.cell.params["check_requests"], len(done))
-        pick = rng.choice(len(done), size=k, replace=False)
-        return [done[j] for j in sorted(pick)]
+        k = self.ctx.cell.params["check_requests"]
+        answered = np.stack([self._answered(b) for b in self.batches])
+        pick = []
+        for r in rng.permutation(self.B)[:k]:
+            calls = np.flatnonzero(answered[:, r])
+            if calls.size:
+                pick.append((int(rng.choice(calls)), int(r)))
+        return sorted(pick)
 
     def _reference(self, quant=None):
         from reference import qwen3

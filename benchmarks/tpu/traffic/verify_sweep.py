@@ -32,6 +32,9 @@ from harness import Check, Context, span
 import work
 
 SPANS = ("simulate_batch",)
+#: what ``end_to_end`` reports and what ``checks`` compares
+END_TO_END = ("verify_mappings_per_s",)
+CHECKS = ("verdicts_wrong", "values_missing", "value_gap", "answers_missing")
 
 
 class Traffic:
