@@ -19,7 +19,6 @@ CONFIG = register(
         n_experts=128,
         top_k=2,
         moe_dense_ff=4864,  # Arctic's dense-residual MLP in parallel with MoE
-        capacity_factor=1.25,
         rope_theta=10_000.0,
         remat="dots",
         fsdp=True,

@@ -42,11 +42,22 @@ class ModelConfig:
     m_rope: bool = False  # Qwen2-VL multimodal RoPE (3 sections)
     m_rope_sections: Tuple[int, int, int] = (16, 24, 24)  # t, h, w (per half-dim)
 
+    # --- multi-head latent attention (DeepSeek-V3 MLA; 0 = GQA) ---
+    kv_lora_rank: int = 0  # width of the cached, normalised KV latent
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0  # rotated part of q and k; k's is shared by the heads
+    v_head_dim: int = 0
+
     # --- MoE ---
-    n_experts: int = 0
+    n_experts: int = 0  # the router's width: every expert of the model
     top_k: int = 0
-    moe_dense_ff: int = 0  # Arctic-style parallel dense residual MLP width
-    capacity_factor: float = 1.25
+    moe_dense_ff: int = 0  # parallel dense branch: Arctic's residual MLP, DeepSeek's shared experts
+    router: str = "softmax"  # softmax | sigmoid (+ a correction bias used for selection only)
+    routed_scale: float = 1.0  # multiplies the normalised top-k weights
+    held_experts: int = 0  # experts this chip holds, from first_held_expert (0 -> all)
+    first_held_expert: int = 0
+    n_dense_layers: int = 0  # leading layers with a dense MLP of width dense_ff
+    dense_ff: int = 0
 
     # --- SSM ---
     ssm_state: int = 0
@@ -64,6 +75,8 @@ class ModelConfig:
     enc_seq: int = 1500  # audio frame positions (frontend is a stub)
 
     # --- numerics / memory policy ---
+    rms_norm_eps: float = 1e-6  # read by the moe family; the others use the same default
+    tie_embeddings: bool = True  # False: an output head of its own (moe family)
     dtype: str = "bfloat16"
     remat: str = "nothing"  # nothing | dots | full(=no remat)
     attn_impl: str = "banded"  # banded (flash-style) | naive (masked full)
@@ -101,6 +114,11 @@ class ModelConfig:
         q = self.n_heads * hd
         kv = self.n_kv_heads * hd
         attn = d * q + 2 * d * kv + q * d  # wq, wk, wv, wo
+        if self.kv_lora_rank:  # MLA: wq, wkv_a, kv_norm, wkv_b, wo
+            H, r, rope = self.n_heads, self.kv_lora_rank, self.qk_rope_head_dim
+            attn = (d * H * (self.qk_nope_head_dim + rope) + d * (r + rope) + r
+                    + r * H * (self.qk_nope_head_dim + self.v_head_dim)
+                    + H * self.v_head_dim * d)
         norms = 2 * d
         if self.qk_norm:
             norms += 2 * hd
@@ -111,11 +129,14 @@ class ModelConfig:
             n = self.n_layers * per_layer
         elif self.family == "moe":
             router = d * self.n_experts
-            n_exp = self.n_experts if not active_only else self.top_k
+            if self.router == "sigmoid":
+                router += self.n_experts  # the correction bias
+            n_exp = (self.held_experts or self.n_experts) if not active_only else self.top_k
             experts = n_exp * 3 * d * self.d_ff
             dense_res = 3 * d * self.moe_dense_ff if self.moe_dense_ff else 0
             per_layer = attn + router + experts + dense_res + norms
-            n = self.n_layers * per_layer
+            dense_layer = attn + 3 * d * self.dense_ff + norms
+            n = (self.n_layers - self.n_dense_layers) * per_layer + self.n_dense_layers * dense_layer
         elif self.family == "ssm":
             di, ns = self.d_inner, self.ssm_state
             per_layer = (
@@ -139,7 +160,9 @@ class ModelConfig:
             n = enc + dec
         else:
             raise ValueError(self.family)
-        n += self.vocab_size * d  # tied embedding / output head
+        n += self.vocab_size * d  # embedding, which is also the output head when tied
+        if not self.tie_embeddings:
+            n += self.vocab_size * d
         return n
 
 
@@ -165,7 +188,7 @@ SHAPES: Dict[str, ShapeSpec] = {
 
 
 def shape_applicable(cfg: ModelConfig, shape: ShapeSpec) -> Tuple[bool, str]:
-    """Assignment skip rules (documented in DESIGN.md §4)."""
+    """Assignment skip rules: long_500k runs only on sub-quadratic archs."""
     if shape.name == "long_500k":
         sub_quadratic = (
             cfg.family in ("ssm", "hybrid") or cfg.sliding_window > 0
@@ -192,6 +215,7 @@ ARCH_IDS = [
     "granite_moe_1b_a400m",
     "falcon_mamba_7b",
     "qwen2_vl_72b",
+    "moonlight_16b_a3b",
 ]
 
 
@@ -237,6 +261,10 @@ def smoke_config(arch_id: str) -> ModelConfig:
     )
     if cfg.family == "moe":
         kw.update(n_experts=4, top_k=2, moe_dense_ff=64 if cfg.moe_dense_ff else 0)
+    if cfg.kv_lora_rank:
+        kw.update(n_layers=3, n_kv_heads=4, kv_lora_rank=32, qk_nope_head_dim=16,
+                  qk_rope_head_dim=8, v_head_dim=16, n_experts=8, d_ff=32,
+                  n_dense_layers=min(cfg.n_dense_layers, 1), dense_ff=128)
     if cfg.family in ("ssm", "hybrid"):
         kw.update(ssm_state=8, ssm_heads=4)
     if cfg.family == "hybrid":

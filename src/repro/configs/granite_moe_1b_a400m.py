@@ -19,7 +19,6 @@ CONFIG = register(
         n_experts=32,
         top_k=8,
         moe_dense_ff=0,  # no dense residual branch
-        capacity_factor=1.25,
         rope_theta=10_000.0,
         remat="nothing",
         fsdp=False,

@@ -169,7 +169,7 @@ def banded_attention(
     use", applied to the attention score matrix).
     """
     B, T, Hq, hd = q.shape
-    Hkv = k.shape[2]
+    Hkv, dv = k.shape[2], v.shape[-1]  # values may be narrower than q and k (MLA)
     G = Hq // Hkv
     scale = 1.0 / math.sqrt(hd)
     chunk = min(chunk, T)
@@ -180,7 +180,7 @@ def banded_attention(
     NEG = jnp.float32(-1e30)
     m = jnp.full((B, Hkv, G, T), NEG)
     l = jnp.zeros((B, Hkv, G, T), jnp.float32)
-    o = jnp.zeros((B, Hkv, G, T, hd), jnp.float32)
+    o = jnp.zeros((B, Hkv, G, T, dv), jnp.float32)
 
     idx = jnp.arange(chunk)
     max_diag = n
@@ -191,7 +191,7 @@ def banded_attention(
         nb = n - d  # blocks on this diagonal
         qs = qg[:, d * chunk :].reshape(B, nb, chunk, Hkv, G, hd)
         ks = k[:, : nb * chunk].reshape(B, nb, chunk, Hkv, hd)
-        vs = v[:, : nb * chunk].reshape(B, nb, chunk, Hkv, hd)
+        vs = v[:, : nb * chunk].reshape(B, nb, chunk, Hkv, dv)
         # absolute positions inside the tile
         qpos = (jnp.arange(nb)[:, None] + d) * chunk + idx[None, :]  # (nb, chunk)
         kpos = jnp.arange(nb)[:, None] * chunk + idx[None, :]
@@ -210,7 +210,7 @@ def banded_attention(
         # bm/bl: (B, nb, Hkv, G, chunk); bo: (B, nb, Hkv, G, chunk, hd)
         bm = jnp.moveaxis(bm, 1, 3).reshape(B, Hkv, G, nb * chunk)
         bl = jnp.moveaxis(bl, 1, 3).reshape(B, Hkv, G, nb * chunk)
-        bo = jnp.moveaxis(bo, 1, 3).reshape(B, Hkv, G, nb * chunk, hd)
+        bo = jnp.moveaxis(bo, 1, 3).reshape(B, Hkv, G, nb * chunk, dv)
         sl = slice(d * chunk, None)
         m2, l2, o2 = _merge(m[..., sl], l[..., sl], o[..., sl, :], bm, bl, bo)
         m = m.at[..., sl].set(m2)
@@ -218,7 +218,7 @@ def banded_attention(
         o = o.at[..., sl, :].set(o2)
 
     out = o / jnp.maximum(l[..., None], 1e-30)
-    out = jnp.moveaxis(out, 3, 1).reshape(B, T, Hq, hd)
+    out = jnp.moveaxis(out, 3, 1).reshape(B, T, Hq, dv)
     return out.astype(q.dtype)
 
 
@@ -243,7 +243,7 @@ def naive_attention(
     s = jnp.where(mask, s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", p, v.astype(jnp.float32))
-    return out.reshape(B, T, Hq, hd).astype(q.dtype)
+    return out.reshape(B, T, Hq, v.shape[-1]).astype(q.dtype)
 
 
 def decode_attention(
@@ -373,6 +373,11 @@ def embed_param_spec(cfg) -> Dict[str, Spec]:
 
 def embed_lookup(emb, tokens):
     return jnp.take(emb, tokens, axis=0)
+
+
+def output_head(cfg, params) -> jax.Array:
+    """The (vocab, d_model) output head: the embedding when tied."""
+    return params["emb"] if cfg.tie_embeddings else params["head"]
 
 
 def chunked_xent(hidden: jax.Array, emb: jax.Array, labels: jax.Array, chunk: int) -> jax.Array:

@@ -14,8 +14,6 @@ from __future__ import annotations
 
 from typing import Dict, Optional, Tuple, Union
 
-import jax
-import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.models.layers import Spec, spec_map
@@ -45,7 +43,6 @@ def logical_rules(cfg, *, multi_pod: bool = False) -> Dict[str, Axis]:
         "state": None,
         "conv": None,
         "dt": None,
-        "capacity": ("data",),  # MoE dispatch buffer token-capacity dim
     }
     return rules
 
@@ -122,19 +119,3 @@ def batch_pspec(global_batch: int, mesh: Mesh, multi_pod: bool) -> P:
         return P("data")
     return P(None)
 
-
-# ---------------------------------------------------------------------------
-# In-graph constraints (used by the MoE dispatch path)
-# ---------------------------------------------------------------------------
-
-
-def constrain(x: jax.Array, *axis_names: Optional[str]) -> jax.Array:
-    """with_sharding_constraint by mesh-axis names; no-op without a mesh."""
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is None or mesh.empty:
-            return x
-        present = [a if (a is None or a in mesh.shape) else None for a in axis_names]
-        return jax.lax.with_sharding_constraint(x, P(*present))
-    except Exception:
-        return x

@@ -5,6 +5,7 @@ what the dry-run lowers at production shapes (decode_32k / long_500k).
 """
 from __future__ import annotations
 
+import functools
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -32,13 +33,25 @@ def generate(
         # exactly zero new tokens: prefill only (cache stays usable for a
         # later decode); the old loop emitted one token here regardless
         tokens = jnp.zeros((prompts.shape[0], 0), jnp.int32)
-        return tokens, {"cache_length": int(cache["length"][0])}
-    serve = jax.jit(steps_lib.make_serve_step(cfg), donate_argnums=(1,))
-    cache = grow_cache(cache, max_new_tokens, window=cfg.sliding_window)
+        return tokens, _info(cache)
+    grow = functools.partial(grow_cache, extra=max_new_tokens, window=cfg.sliding_window)
     next_tok = jnp.argmax(logits[:, -1, :], -1).astype(jnp.int32)[:, None]
     out: List[jax.Array] = [next_tok]
+    if max_new_tokens > 1:
+        # built while the prefill runs, before the cache grows: a grown
+        # cache's memory may have to wait for the prefill's to be freed
+        serve = jax.jit(steps_lib.make_serve_step(cfg), donate_argnums=(1,)).lower(
+            params, jax.eval_shape(grow, cache), next_tok).compile()
+    cache = grow(cache)
     for _ in range(max_new_tokens - 1):
         cache, next_tok, _ = serve(params, cache, next_tok)
         out.append(next_tok)
     tokens = jnp.concatenate(out, axis=1)
-    return tokens, {"cache_length": int(cache["length"][0])}
+    return tokens, _info(cache)
+
+
+def _info(cache) -> Dict:
+    """The call's cache length and whatever the model counted in its cache
+    (``counters``), in one transfer from the device."""
+    length, counters = jax.device_get((cache["length"][0], cache.get("counters", {})))
+    return {"cache_length": int(length), **counters}

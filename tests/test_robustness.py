@@ -308,18 +308,23 @@ def test_compile_generous_deadline_is_bit_identical():
 # -- graceful degradation -----------------------------------------------------
 
 
-def _ensure_never_maps():
-    """Register a test mapper that always exhausts its II range.  No
-    ``jobs`` metadata: it must NOT extend the collect grid session-wide."""
-    if "_rt_never_maps" not in MAPPERS:
-        @register_mapper("_rt_never_maps",
-                         description="test-only: always infeasible")
-        class _NeverMaps:
-            def __init__(self, arch, seed=0, time_budget=None):
-                pass
+@pytest.fixture
+def never_maps(monkeypatch):
+    """A test mapper that always exhausts its II range, registered for one
+    test only: left registered, it would reach every later test of the
+    process that walks the registry.  No ``jobs`` metadata: it must NOT
+    extend the collect grid."""
+    monkeypatch.setattr(MAPPERS, "_items", dict(MAPPERS._items))
+    monkeypatch.setattr(MAPPERS, "_meta", dict(MAPPERS._meta))
 
-            def map(self, dfg):
-                return None
+    @register_mapper("_rt_never_maps",
+                     description="test-only: always infeasible")
+    class _NeverMaps:
+        def __init__(self, arch, seed=0, time_budget=None):
+            pass
+
+        def map(self, dfg):
+            return None
     return "_rt_never_maps"
 
 
@@ -337,8 +342,8 @@ def test_fallback_on_timeout_degrades_instead_of_raising():
     assert res.ii is not None  # the cheap fallback produced a mapping
 
 
-def test_fallback_on_infeasibility():
-    name = _ensure_never_maps()
+def test_fallback_on_infeasibility(never_maps):
+    name = never_maps
     bare = compile_workload("atax", unroll=2, mapper=name)
     assert bare.ii is None and bare.degraded is None  # no fallback: unmapped
     with pytest.raises(MappingInfeasible):
@@ -373,8 +378,8 @@ def test_degraded_artifact_roundtrips_schema_v5(tmp_path):
     assert "degraded" not in clean.summary()
 
 
-def test_degraded_results_are_never_stored(tmp_path):
-    name = _ensure_never_maps()
+def test_degraded_results_are_never_stored(tmp_path, never_maps):
+    name = never_maps
     store = ArtifactStore(str(tmp_path / "store"))
     res = compile_workload("atax", unroll=2, mapper=name,
                            fallback_mapper="node_greedy", store=store)

@@ -7,10 +7,10 @@ import pytest
 
 from repro.configs import smoke_config
 from repro.models import zoo
-from repro.models.layers import init_of
+from repro.models.layers import init_of, output_head
 
 ARCHS = ["llama3_2_3b", "h2o_danube_3_4b", "falcon_mamba_7b", "zamba2_1_2b",
-         "granite_moe_1b_a400m", "whisper_tiny"]
+         "granite_moe_1b_a400m", "whisper_tiny", "moonlight_16b_a3b"]
 
 
 @pytest.mark.parametrize("arch", ARCHS)
@@ -38,7 +38,7 @@ def test_prefill_then_decode_matches_forward(arch):
     h = zoo.forward(cfg, params, full_batch)
     if isinstance(h, tuple):
         h = h[0]
-    ref_logits = (h @ params["emb"].T).astype(jnp.float32)
+    ref_logits = (h @ output_head(cfg, params).T).astype(jnp.float32)
     for i in range(4):
         got = np.asarray(decode_logits[i], np.float32)
         want = np.asarray(ref_logits[:, T + i], np.float32)
@@ -116,3 +116,23 @@ def test_compile_cache_placement(monkeypatch):
         assert cc.REPO_CACHE_DIR.parent.joinpath("pyproject.toml").exists()
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
+
+
+@pytest.mark.parametrize("keys", [("k", "v"), ("latent",)])
+def test_grow_cache_grows_every_sequence_cache(keys):
+    """Keys and values, or latent attention's latents, gain the decode
+    headroom along the sequence; the new slots are empty (``pos`` -1) and
+    what else the cache holds passes through."""
+    from repro.serve.kvcache import grow_cache
+
+    cache = {k: jnp.ones((3, 2, 5, 4), jnp.bfloat16) for k in keys}
+    cache.update(pos=jnp.broadcast_to(jnp.arange(5, dtype=jnp.int32), (2, 5)),
+                 length=jnp.full((2,), 5, jnp.int32),
+                 counters={"moe_rows": jnp.arange(3, dtype=jnp.int32)})
+    grown = grow_cache(cache, 3)
+    for k in keys:
+        assert grown[k].shape == (3, 2, 8, 4)
+        np.testing.assert_array_equal(np.asarray(grown[k][:, :, 5:], np.float32), 0)
+    np.testing.assert_array_equal(np.asarray(grown["pos"][0]),
+                                  [0, 1, 2, 3, 4, -1, -1, -1])
+    assert grown["counters"] is cache["counters"]
