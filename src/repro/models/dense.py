@@ -159,10 +159,14 @@ def prefill(cfg, params, batch) -> Tuple[Dict, jax.Array]:
 
 
 def decode_step(cfg, params, cache, tokens) -> Tuple[Dict, jax.Array]:
-    """One decode step: tokens (B, 1) -> (new cache, logits (B, 1, V))."""
+    """One decode step: tokens (B, 1) -> (new cache, logits (B, 1, V)).
+
+    Each layer reads its slice of the cache in place and returns the new
+    position's K/V row; one update writes every layer's row into the
+    (donated) cache after the layers have run."""
     B = tokens.shape[0]
-    S = cache["k"].shape[2]
-    hd = cfg.resolved_head_dim
+    ks, vs = cache["k"], cache["v"]
+    S = ks.shape[2]
     length = cache["length"]  # (B,)
     positions = length[:, None].astype(jnp.int32)  # (B, 1)
     if cfg.m_rope:
@@ -178,21 +182,24 @@ def decode_step(cfg, params, cache, tokens) -> Tuple[Dict, jax.Array]:
     else:
         valid = new_pos >= 0
     valid &= new_pos <= length[:, None]
+    # the slot the new row overwrites holds a position the step no longer sees
+    valid &= jnp.arange(S)[None, :] != slot[:, None]
 
     def block(xx, scan_in):
-        ww, kc, vc = scan_in
+        ww, i = scan_in
         h = L.rms_norm(xx, ww["ln1"])
         q, k, v = L.attention_qkv(cfg, ww["attn"], h, positions)
-        kc = kc.at[barange, slot].set(k.reshape(B, -1))
-        vc = vc.at[barange, slot].set(v.reshape(B, -1))
-        o = L.decode_attention(
-            q, kc.reshape(B, S, cfg.n_kv_heads, hd), vc.reshape(B, S, cfg.n_kv_heads, hd), valid
-        )
+        o = L.decode_attention_row(q, ks[i], vs[i], valid, k, v)
         xx = xx + o.reshape(B, 1, -1) @ ww["attn"]["wo"]
         xx = xx + L.swiglu(ww["mlp"], L.rms_norm(xx, ww["ln2"]))
-        return xx, (kc, vc)
+        return xx, (k.reshape(B, -1).astype(ks.dtype), v.reshape(B, -1).astype(vs.dtype))
 
-    x, (ks, vs) = L.scan_layers(cfg, block, x, (params["layers"], cache["k"], cache["v"]))
+    x, (k_rows, v_rows) = L.scan_layers(
+        cfg, block, x, (params["layers"], jnp.arange(cfg.n_layers))
+    )
+    for b in range(B):  # in place: a scatter over (B, S) would re-lay the cache
+        ks = lax.dynamic_update_slice(ks, k_rows[:, b, None, None], (0, b, slot[b], 0))
+        vs = lax.dynamic_update_slice(vs, v_rows[:, b, None, None], (0, b, slot[b], 0))
     x = L.rms_norm(x, params["ln_f"])
     logits = (x @ params["emb"].T).astype(jnp.float32)
     new_cache = {"k": ks, "v": vs, "pos": new_pos, "length": length + 1}

@@ -264,6 +264,42 @@ def decode_attention(
     return out.reshape(B, 1, Hq, hd).astype(q.dtype)
 
 
+def decode_attention_row(
+    q: jax.Array,  # (B, 1, Hq, hd)
+    k_cache: jax.Array,  # (B, S, Hkv*hd), the layer's cache before the step
+    v_cache: jax.Array,
+    valid: jax.Array,  # (B, S) bool — which cached slots this step attends
+    k: jax.Array,  # (B, 1, Hkv, hd), the new position's
+    v: jax.Array,
+) -> jax.Array:
+    """``decode_attention`` for a cache that does not hold the new position
+    yet: the cached slots in ``valid`` and the new ``k``, ``v`` (which every
+    query attends) in one softmax, in float32.  The cache is only read, so
+    the caller writes the new row once, after every layer has run.
+
+    Both products run over the cache as it is stored, each row ``Hkv*hd``
+    wide: a query is spread over the whole row, zero outside its KV head,
+    and each query keeps its own head's block of the weighted values.  So
+    the compiler reads the cache in place instead of laying each head out
+    apart; the products outside a query's head are by exact zeros.
+    """
+    B, _, Hq, hd = q.shape
+    Hkv = k.shape[2]
+    G = Hq // Hkv
+    S = k_cache.shape[1]
+    own = (jnp.arange(Hkv)[:, None] == jnp.arange(Hkv))[None, :, None, :, None]
+    qg = q.reshape(B, Hkv, G, 1, hd).astype(jnp.float32)
+    q_row = jnp.where(own, qg, 0.0).reshape(B, Hq, Hkv * hd)
+    s_old = jnp.einsum("bqc,bsc->bqs", q_row, k_cache.astype(jnp.float32))
+    s_old = jnp.where(valid[:, None, None, :], s_old.reshape(B, Hkv, G, S) / math.sqrt(hd), -1e30)
+    s_new = jnp.einsum("bhgd,bhd->bhg", qg[:, :, :, 0], k.reshape(B, Hkv, hd).astype(jnp.float32))
+    p = jax.nn.softmax(jnp.concatenate([s_old, s_new[..., None] / math.sqrt(hd)], -1), axis=-1)
+    o_row = jnp.einsum("bqs,bsc->bqc", p[..., :-1].reshape(B, Hq, S), v_cache.astype(jnp.float32))
+    out = jnp.where(own, o_row.reshape(B, Hkv, G, Hkv, hd), 0.0).sum(axis=3)
+    out = out + p[..., -1:] * v.reshape(B, Hkv, 1, hd).astype(jnp.float32)
+    return out.reshape(B, 1, Hq, hd).astype(q.dtype)
+
+
 # ---------------------------------------------------------------------------
 # GQA attention layer
 # ---------------------------------------------------------------------------

@@ -136,3 +136,91 @@ def test_grow_cache_grows_every_sequence_cache(keys):
     np.testing.assert_array_equal(np.asarray(grown["pos"][0]),
                                   [0, 1, 2, 3, 4, -1, -1, -1])
     assert grown["counters"] is cache["counters"]
+
+
+def _ring_pos(lengths, S):
+    """Absolute position each slot holds once ``lengths[b]`` positions have
+    been written with slot = position % S (-1: empty)."""
+    s = np.arange(S)[None]
+    n = np.asarray(lengths)[:, None]
+    p = s + S * ((n - 1 - s) // S)
+    return np.where(p >= 0, p, -1).astype(np.int32)
+
+
+# (Hq, Hkv, window, S, per-row lengths)
+ROW_CASES = {
+    "group1": (4, 4, 0, 16, [0, 3, 9, 15]),
+    "group5": (40, 8, 0, 16, [1, 7, 15]),
+    "ring_wrapped": (10, 2, 8, 8, [5, 8, 13, 30]),
+    "ring_below_window": (10, 2, 12, 8, [3, 7]),
+    "ring_below_window_wrapped": (10, 2, 12, 8, [9, 20]),
+}
+
+
+@pytest.mark.parametrize("case", ROW_CASES)
+def test_decode_attention_row_matches_full_cache(case):
+    """The new-row attention over the cache as it was before the step equals
+    writing the row first and attending the whole cache, as decode did
+    before (float32)."""
+    from repro.models.layers import decode_attention, decode_attention_row
+
+    Hq, Hkv, window, S, lengths = ROW_CASES[case]
+    B, hd = len(lengths), 16
+    rng = np.random.default_rng(7)
+    f32 = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)
+    q, k, v = f32(B, 1, Hq, hd), f32(B, 1, Hkv, hd), f32(B, 1, Hkv, hd)
+    kc, vc = f32(B, S, Hkv * hd), f32(B, S, Hkv * hd)
+    length = jnp.asarray(lengths, jnp.int32)
+    pos = jnp.asarray(_ring_pos(lengths, S))
+    b, slot = jnp.arange(B), length % S
+
+    new_pos = pos.at[b, slot].set(length)
+    valid = (new_pos >= 0) & (new_pos <= length[:, None])
+    if window:
+        valid &= (length[:, None] - new_pos) < window
+    want = decode_attention(
+        q, kc.at[b, slot].set(k.reshape(B, -1)).reshape(B, S, Hkv, hd),
+        vc.at[b, slot].set(v.reshape(B, -1)).reshape(B, S, Hkv, hd), valid)
+    got = decode_attention_row(q, kc, vc, valid & (jnp.arange(S) != slot[:, None]), k, v)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("arch", ["llama3_2_3b", "h2o_danube_3_4b"])
+def test_decode_step_writes_only_the_new_row(arch):
+    """No layer hands back a whole K/V cache from the layer scan, and a step
+    leaves every slot but each row's current one bit for bit as it was."""
+    from repro.serve.kvcache import grow_cache
+
+    cfg = smoke_config(arch).replace(n_layers=2, attn_impl="naive")
+    if cfg.sliding_window:
+        cfg = cfg.replace(sliding_window=8)  # a 16-token prompt wraps the ring
+    params = init_of(zoo.param_spec(cfg), jax.random.PRNGKey(0))
+    B, T = 2, 16
+    tokens = jnp.asarray(np.random.default_rng(0).integers(0, cfg.vocab_size, (B, T + 1)),
+                         jnp.int32)
+    cache, _ = zoo.prefill(cfg, params, {"tokens": tokens[:, :T]})
+    cache = grow_cache(cache, 4, window=cfg.sliding_window)
+    step = lambda c: zoo.decode_step(cfg, params, c, tokens[:, T:])
+
+    _, S, kvd = cache["k"].shape[1:]
+    jaxpr = jax.make_jaxpr(step)(cache)
+
+    def scan_outputs(jx):
+        for eqn in jx.eqns:
+            if eqn.primitive.name == "scan":
+                yield from (o.aval.shape for o in eqn.outvars)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from scan_outputs(sub)
+
+    shapes = list(scan_outputs(jaxpr.jaxpr))
+    assert shapes, "decode_step walks its layers with lax.scan"
+    assert not [s for s in shapes if tuple(s[-2:]) == (S, kvd)], shapes
+
+    new, _ = step(cache)
+    slot = np.asarray(cache["length"]) % S
+    for key in ("k", "v"):
+        old, got = np.asarray(cache[key]), np.asarray(new[key])
+        keep = np.ones(old.shape[1:3], bool)
+        keep[np.arange(B), slot] = False
+        np.testing.assert_array_equal(got[:, keep], old[:, keep])
+        assert not np.array_equal(got[:, ~keep], old[:, ~keep])

@@ -62,3 +62,27 @@ def test_motif_pcu_compiles_for_v5e(one_chip, sched):
         lambda x: mp.motif_pcu(sched, 3, x, interpret=False), one_chip,
         ((3, 1 << 20), jnp.float32))
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_dense_serve_step_reads_the_cache_in_place_on_v5e(one_chip):
+    """Qwen3-14B's attention widths at the decode cell's batch and cache
+    length: the compiled serve step's temporaries are smaller than one KV
+    head's keys in one layer, so it neither copies the cache nor lays a
+    layer's slice out anew."""
+    from repro.configs import get_config
+    from repro.models import zoo
+    from repro.models.layers import shapes_of
+    from repro.train import steps
+
+    cfg = get_config("qwen3_14b").replace(n_layers=2, d_model=1024, d_ff=2048,
+                                          vocab_size=2048)
+    B, S = 32, 1152
+    on_chip = lambda t: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), t)
+    args = (on_chip(shapes_of(zoo.param_spec(cfg))),
+            on_chip(shapes_of(zoo.cache_spec(cfg, B, S))),
+            jax.ShapeDtypeStruct((B, 1), jnp.int32, sharding=one_chip))
+    compiled = jax.jit(steps.make_serve_step(cfg), donate_argnums=(1,)).lower(
+        *args).compile()
+    head_keys = B * S * cfg.resolved_head_dim * 2  # bf16
+    assert compiled.memory_analysis().temp_size_in_bytes < head_keys
