@@ -14,9 +14,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.models import dense as D
 from repro.models import layers as L
 from repro.models.layers import Spec
+from repro.serve import kvcache as kv
 
 
 def enc_layer_spec(cfg) -> Dict[str, Spec]:
@@ -42,8 +42,8 @@ def dec_layer_spec(cfg) -> Dict[str, Spec]:
 def param_spec(cfg) -> Dict[str, Spec]:
     return {
         **L.embed_param_spec(cfg),
-        "encoder": D._stack(enc_layer_spec(cfg), cfg.n_enc_layers),
-        "decoder": D._stack(dec_layer_spec(cfg), cfg.n_layers),
+        "encoder": L.stack_layers(enc_layer_spec(cfg), cfg.n_enc_layers),
+        "decoder": L.stack_layers(dec_layer_spec(cfg), cfg.n_layers),
         "ln_enc": Spec((cfg.d_model,), ("embed",), init="ones"),
         "ln_f": Spec((cfg.d_model,), ("embed",), init="ones"),
     }
@@ -114,14 +114,10 @@ def loss_fn(cfg, params, batch):
 def cache_spec(cfg, batch: int, seq_len: int) -> Dict[str, Spec]:
     kvd = cfg.n_kv_heads * cfg.resolved_head_dim
     Ld = cfg.n_layers
-    seq_axis = "cache_seq" if batch == 1 else None
     return {
-        "k": Spec((Ld, batch, seq_len, kvd), ("layers", "batch", seq_axis, "kv_heads")),
-        "v": Spec((Ld, batch, seq_len, kvd), ("layers", "batch", seq_axis, "kv_heads")),
+        **kv.spec(cfg, Ld, batch, seq_len),
         "xk": Spec((Ld, batch, cfg.enc_seq, kvd), ("layers", "batch", None, "kv_heads")),
         "xv": Spec((Ld, batch, cfg.enc_seq, kvd), ("layers", "batch", None, "kv_heads")),
-        "pos": Spec((batch, seq_len), ("batch", seq_axis), jnp.int32),
-        "length": Spec((batch,), ("batch",), jnp.int32),
     }
 
 
@@ -136,8 +132,8 @@ def prefill(cfg, params, batch):
         xx, (self_kv, cross_kv) = _dec_block(cfg, ww, xx, positions, enc_out, want_kv=True)
         (k, v), (xk, xv) = self_kv, cross_kv
         return xx, (
-            k.reshape(B, T, -1),
-            v.reshape(B, T, -1),
+            kv.prompt_rows(cfg, k),
+            kv.prompt_rows(cfg, v),
             xk.reshape(B, cfg.enc_seq, -1),
             xv.reshape(B, cfg.enc_seq, -1),
         )
@@ -148,40 +144,25 @@ def prefill(cfg, params, batch):
     x, (ks, vs, xks, xvs) = L.scan_layers(cfg, block, x, params["decoder"])
     x = L.rms_norm(x, params["ln_f"])
     logits = (x[:, -1:] @ params["emb"].T).astype(jnp.float32)
-    cache = {
-        "k": ks,
-        "v": vs,
-        "xk": xks,
-        "xv": xvs,
-        "pos": jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None], (B, T)),
-        "length": jnp.full((B,), T, jnp.int32),
-    }
-    return cache, logits
+    return {"k": ks, "v": vs, "xk": xks, "xv": xvs, **kv.prompt_state(cfg, B, T)}, logits
 
 
 def decode_step(cfg, params, cache, tokens):
     B = tokens.shape[0]
-    S = cache["k"].shape[2]
     hd = cfg.resolved_head_dim
     length = cache["length"]
     positions = length[:, None].astype(jnp.int32)
     x = L.embed_lookup(params["emb"], tokens)
-    slot = (length % S).astype(jnp.int32)
-    barange = jnp.arange(B)
-    new_pos = cache["pos"].at[barange, slot].set(length)
-    valid = (new_pos >= 0) & (new_pos <= length[:, None])
+    step = kv.step(cache, cfg.sliding_window)
     xvalid = jnp.ones((B, cfg.enc_seq), bool)
 
     def block(xx, scan_in):
-        ww, kc, vc, xk, xv = scan_in
-        h = L.rms_norm(xx, ww["ln1"])
-        q, k, v = L.attention_qkv(cfg, ww["self_attn"], h, positions)
-        kc = kc.at[barange, slot].set(k.reshape(B, -1))
-        vc = vc.at[barange, slot].set(v.reshape(B, -1))
-        o = L.decode_attention(
-            q, kc.reshape(B, S, cfg.n_kv_heads, hd), vc.reshape(B, S, cfg.n_kv_heads, hd), valid
+        ww, i, xk, xv = scan_in
+        h, (k, v) = L.decode_attention_layer(
+            cfg, ww["self_attn"], L.rms_norm(xx, ww["ln1"]), positions,
+            cache["k"], cache["v"], i, step.mask,
         )
-        xx = xx + o.reshape(B, 1, -1) @ ww["self_attn"]["wo"]
+        xx = xx + h
         # cross-attention against the static encoder KV
         hh = L.rms_norm(xx, ww["ln_x"])
         q = (hh @ ww["cross_attn"]["wq"]).reshape(B, 1, cfg.n_heads, hd)
@@ -194,12 +175,13 @@ def decode_step(cfg, params, cache, tokens):
         )
         xx = xx + o.reshape(B, 1, -1) @ ww["cross_attn"]["wo"]
         xx = xx + L.swiglu(ww["mlp"], L.rms_norm(xx, ww["ln2"]))
-        return xx, (kc, vc)
+        return xx, (kv.row(k, cache["k"]), kv.row(v, cache["v"]))
 
-    x, (ks, vs) = L.scan_layers(
-        cfg, block, x, (params["decoder"], cache["k"], cache["v"], cache["xk"], cache["xv"])
+    x, (k_rows, v_rows) = L.scan_layers(
+        cfg, block, x, (params["decoder"], jnp.arange(cfg.n_layers), cache["xk"], cache["xv"])
     )
     x = L.rms_norm(x, params["ln_f"])
     logits = (x @ params["emb"].T).astype(jnp.float32)
-    new_cache = dict(cache, k=ks, v=vs, pos=new_pos, length=length + 1)
+    seq = kv.write_rows(cache, {"k": k_rows, "v": v_rows}, step.slot)
+    new_cache = dict(cache, **seq, pos=step.pos, length=length + 1)
     return new_cache, logits

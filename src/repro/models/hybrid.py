@@ -14,10 +14,10 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.models import dense as D
 from repro.models import layers as L
 from repro.models import ssm as S
 from repro.models.layers import Spec
+from repro.serve import kvcache as kv
 
 
 def n_groups(cfg) -> Tuple[int, int]:
@@ -38,7 +38,7 @@ def shared_block_spec(cfg) -> Dict[str, Spec]:
 def param_spec(cfg) -> Dict[str, Spec]:
     return {
         **L.embed_param_spec(cfg),
-        "mamba": S._stack(S.mamba2_param_spec(cfg), cfg.n_layers),
+        "mamba": L.stack_layers(S.mamba2_param_spec(cfg), cfg.n_layers),
         "shared": shared_block_spec(cfg),
         "ln_f": Spec((cfg.d_model,), ("embed",), init="ones"),
     }
@@ -103,18 +103,13 @@ def cache_spec(cfg, batch: int, seq_len: int) -> Dict[str, Spec]:
     Di, N, K = cfg.d_inner, cfg.ssm_state, cfg.d_conv
     H, P = cfg.n_ssm_heads, cfg.d_inner // cfg.n_ssm_heads
     g, _ = n_groups(cfg)
-    kvd = cfg.n_kv_heads * cfg.resolved_head_dim
-    seq_axis = "cache_seq" if batch == 1 else None
     return {
         "conv": Spec((cfg.n_layers, batch, K - 1, Di), ("layers", "batch", None, "mlp")),
         "h": Spec(
             (cfg.n_layers, batch, H, P, N), ("layers", "batch", None, "mlp", "state"), jnp.float32
         ),
         # one KV cache per shared-attention application site
-        "k": Spec((g, batch, seq_len, kvd), ("layers", "batch", seq_axis, "kv_heads")),
-        "v": Spec((g, batch, seq_len, kvd), ("layers", "batch", seq_axis, "kv_heads")),
-        "pos": Spec((batch, seq_len), ("batch", seq_axis), jnp.int32),
-        "length": Spec((batch,), ("batch",), jnp.int32),
+        **kv.spec(cfg, g, batch, seq_len),
     }
 
 
@@ -142,7 +137,7 @@ def prefill(cfg, params, batch):
     def group(xx, ws):
         xx, caches = lax.scan(mamba_step_c, xx, ws)
         xx, (k, v) = _shared_attn(cfg, shared, xx, positions, want_kv=True)
-        return xx, (caches, k.reshape(B, T, -1), v.reshape(B, T, -1))
+        return xx, (caches, kv.prompt_rows(cfg, k), kv.prompt_rows(cfg, v))
 
     x, (gcaches, ks, vs) = L.scan_layers(cfg, group, x, grouped)
     g, rem = n_groups(cfg)
@@ -154,28 +149,14 @@ def prefill(cfg, params, batch):
         hst = jnp.concatenate([hst, tcaches["h"]], 0)
     x = L.rms_norm(x, params["ln_f"])
     logits = (x[:, -1:] @ params["emb"].T).astype(jnp.float32)
-    cache = {
-        "conv": conv,
-        "h": hst,
-        "k": ks,
-        "v": vs,
-        "pos": jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None], (B, T)),
-        "length": jnp.full((B,), T, jnp.int32),
-    }
-    return cache, logits
+    return {"conv": conv, "h": hst, "k": ks, "v": vs, **kv.prompt_state(cfg, B, T)}, logits
 
 
 def decode_step(cfg, params, cache, tokens):
-    B = tokens.shape[0]
-    Smax = cache["k"].shape[2]
-    hd = cfg.resolved_head_dim
     length = cache["length"]
     positions = length[:, None].astype(jnp.int32)
     x = L.embed_lookup(params["emb"], tokens)
-    slot = (length % Smax).astype(jnp.int32)
-    barange = jnp.arange(B)
-    new_pos = cache["pos"].at[barange, slot].set(length)
-    valid = (new_pos >= 0) & (new_pos <= length[:, None])
+    step = kv.step(cache, cfg.sliding_window)
     grouped, tail = _split_groups(cfg, params["mamba"])
     shared = params["shared"]
     g, rem = n_groups(cfg)
@@ -186,25 +167,23 @@ def decode_step(cfg, params, cache, tokens):
         out, nc = S.mamba2_block(cfg, ww, L.rms_norm(xx, ww["ln"]), {"conv": conv, "h": h})
         return xx + out, (nc["conv"], nc["h"])
 
-    def group(carry, scan_in):
-        xx = carry
-        ws, conv_g, h_g, kc, vc = scan_in
+    def group(xx, scan_in):
+        ws, conv_g, h_g, i = scan_in
         xx, (nconv, nh) = lax.scan(mamba_dec, xx, (ws, conv_g, h_g))
-        # shared attention with this site's KV cache
-        hh = L.rms_norm(xx, shared["ln1"])
-        q, k, v = L.attention_qkv(cfg, shared["attn"], hh, positions)
-        kc = kc.at[barange, slot].set(k.reshape(B, -1))
-        vc = vc.at[barange, slot].set(v.reshape(B, -1))
-        o = L.decode_attention(
-            q, kc.reshape(B, Smax, cfg.n_kv_heads, hd), vc.reshape(B, Smax, cfg.n_kv_heads, hd), valid
+        # shared attention over site i's KV cache
+        h, (k, v) = L.decode_attention_layer(
+            cfg, shared["attn"], L.rms_norm(xx, shared["ln1"]), positions,
+            cache["k"], cache["v"], i, step.mask,
         )
-        xx = xx + o.reshape(B, 1, -1) @ shared["attn"]["wo"]
+        xx = xx + h
         xx = xx + L.swiglu(shared["mlp"], L.rms_norm(xx, shared["ln2"]))
-        return xx, (nconv, nh, kc, vc)
+        return xx, (nconv, nh, kv.row(k, cache["k"]), kv.row(v, cache["v"]))
 
     conv_g = cache["conv"][: g * k_every].reshape((g, k_every) + cache["conv"].shape[1:])
     h_g = cache["h"][: g * k_every].reshape((g, k_every) + cache["h"].shape[1:])
-    x, (nconv, nh, ks, vs) = L.scan_layers(cfg, group, x, (grouped, conv_g, h_g, cache["k"], cache["v"]))
+    x, (nconv, nh, k_rows, v_rows) = L.scan_layers(
+        cfg, group, x, (grouped, conv_g, h_g, jnp.arange(g))
+    )
     conv = nconv.reshape((g * k_every,) + nconv.shape[2:])
     hst = nh.reshape((g * k_every,) + nh.shape[2:])
     if rem:
@@ -215,12 +194,6 @@ def decode_step(cfg, params, cache, tokens):
         hst = jnp.concatenate([hst, th], 0)
     x = L.rms_norm(x, params["ln_f"])
     logits = (x @ params["emb"].T).astype(jnp.float32)
-    new_cache = {
-        "conv": conv,
-        "h": hst,
-        "k": ks,
-        "v": vs,
-        "pos": new_pos,
-        "length": length + 1,
-    }
+    seq = kv.write_rows(cache, {"k": k_rows, "v": v_rows}, step.slot)
+    new_cache = {"conv": conv, "h": hst, **seq, "pos": step.pos, "length": length + 1}
     return new_cache, logits

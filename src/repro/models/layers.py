@@ -46,6 +46,13 @@ def spec_map(fn, tree):
     return jax.tree.map(fn, tree, is_leaf=lambda x: isinstance(x, Spec))
 
 
+def stack_layers(spec_tree, n: int):
+    """``spec_tree`` for ``n`` layers stacked on a leading ``layers`` axis."""
+    return spec_map(
+        lambda s: Spec((n,) + s.shape, ("layers",) + s.axes, s.dtype, s.init), spec_tree
+    )
+
+
 def shapes_of(tree):
     return spec_map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype), tree)
 
@@ -252,6 +259,9 @@ def decode_attention(
     v_cache: jax.Array,
     valid: jax.Array,  # (B, S) bool — which cache slots are live
 ) -> jax.Array:
+    """One query position over a whole cache that holds no new row: the
+    static encoder keys and values of cross-attention, and the reference
+    that ``decode_attention_row`` is tested against."""
     B, _, Hq, hd = q.shape
     Hkv = k_cache.shape[2]
     G = Hq // Hkv
@@ -272,7 +282,7 @@ def decode_attention_row(
     k: jax.Array,  # (B, 1, Hkv, hd), the new position's
     v: jax.Array,
 ) -> jax.Array:
-    """``decode_attention`` for a cache that does not hold the new position
+    """Attention of a new position over a cache that does not hold it
     yet: the cached slots in ``valid`` and the new ``k``, ``v`` (which every
     query attends) in one softmax, in float32.  The cache is only read, so
     the caller writes the new row once, after every layer has run.
@@ -375,6 +385,22 @@ def attention_layer(
         o = naive_attention(q, k, v, causal=causal, window=cfg.sliding_window)
     out = o.reshape(B, T, -1) @ w["wo"]
     return out, (k, v)
+
+
+def decode_attention_layer(cfg, w, x, positions, k_cache, v_cache, i, valid):
+    """Self-attention of one new position per sequence (decode).
+
+    ``x`` (B, 1, D), already normed; ``k_cache``/``v_cache`` (n, B, S,
+    Hkv*hd), stacked caches as they were before the step, of which layer
+    (or site) ``i`` is read in place; ``valid`` (B, S), the cached slots
+    the step attends.  Returns (out, (k, v)): the new position's keys and
+    values (B, 1, Hkv, hd), for the caller to write after every layer has
+    run.
+    """
+    B = x.shape[0]
+    q, k, v = attention_qkv(cfg, w, x, positions)
+    o = decode_attention_row(q, k_cache[i], v_cache[i], valid, k, v)
+    return o.reshape(B, 1, -1) @ w["wo"], (k, v)
 
 
 # ---------------------------------------------------------------------------

@@ -77,9 +77,9 @@ def attention(cfg, w, x, positions) -> Tuple[jax.Array, jax.Array]:
     k = jnp.concatenate([kv[..., :nope], k_pe], axis=-1)
     v = kv[..., nope:]
     if cfg.attn_impl == "banded":
-        o = L.banded_attention(q, k, v, chunk=min(cfg.attn_chunk, T))
+        o = L.banded_attention(q, k, v, window=cfg.sliding_window, chunk=min(cfg.attn_chunk, T))
     else:
-        o = L.naive_attention(q, k, v)
+        o = L.naive_attention(q, k, v, window=cfg.sliding_window)
     return o.reshape(B, T, -1) @ w["wo"], lat
 
 

@@ -22,10 +22,10 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.models import dense as D
 from repro.models import layers as L
 from repro.models import mla
 from repro.models.layers import Spec
+from repro.serve import kvcache as kv
 
 #: what the layer counts per MoE layer; carried in the serving cache under
 #: ``counters`` and returned by ``serve.loop.generate``
@@ -71,12 +71,12 @@ def _layer_spec(cfg, ffn_key: str, ffn: Dict[str, Spec]) -> Dict[str, Spec]:
 def param_spec(cfg) -> Dict[str, Spec]:
     p = {
         **L.embed_param_spec(cfg),
-        "layers": D._stack(_layer_spec(cfg, "moe", moe_param_spec(cfg)), n_moe_layers(cfg)),
+        "layers": L.stack_layers(_layer_spec(cfg, "moe", moe_param_spec(cfg)), n_moe_layers(cfg)),
         "ln_f": Spec((cfg.d_model,), ("embed",), init="ones"),
     }
     if cfg.n_dense_layers:
         mlp = L.mlp_param_spec(cfg, cfg.dense_ff)
-        p["first_layers"] = D._stack(_layer_spec(cfg, "mlp", mlp), cfg.n_dense_layers)
+        p["first_layers"] = L.stack_layers(_layer_spec(cfg, "mlp", mlp), cfg.n_dense_layers)
     if not cfg.tie_embeddings:
         p["head"] = Spec((cfg.vocab_size, cfg.d_model), ("vocab", "embed"))
     return p
@@ -237,23 +237,11 @@ def loss_fn(cfg, params, batch):
 # Serving
 # ---------------------------------------------------------------------------
 
-cache_len = D.cache_len
-
-
 def cache_spec(cfg, batch: int, seq_len: int) -> Dict[str, Spec]:
     counters = {k: Spec((n_moe_layers(cfg),), (None,), jnp.int32, init="zeros")
                 for k in COUNTERS}
-    if not cfg.kv_lora_rank:
-        return {**D.cache_spec(cfg, batch, seq_len), "counters": counters}
-    S = cache_len(cfg, seq_len)
-    seq_axis = "cache_seq" if batch == 1 else None
-    return {
-        "latent": Spec((cfg.n_layers, batch, S, mla.latent_dim(cfg)),
-                       ("layers", "batch", seq_axis, None)),
-        "pos": Spec((batch, S), ("batch", seq_axis), jnp.int32),
-        "length": Spec((batch,), ("batch",), jnp.int32),
-        "counters": counters,
-    }
+    latent = mla.latent_dim(cfg) if cfg.kv_lora_rank else 0
+    return {**kv.spec(cfg, cfg.n_layers, batch, seq_len, latent=latent), "counters": counters}
 
 
 def _counted(counters, routed, rows: int):
@@ -269,7 +257,6 @@ def _logits(cfg, params, x):
 def prefill(cfg, params, batch):
     tokens = batch["tokens"]
     B, T = tokens.shape
-    S = cache_len(cfg, T)
     x = L.embed_lookup(params["emb"], tokens)
     positions = _positions(B, T)
 
@@ -277,10 +264,7 @@ def prefill(cfg, params, batch):
         (ww,) = scan_in
         h, kept = _attend(cfg, ww["attn"], _norm(cfg, xx, ww["ln1"]), positions)
         xx, _, routed = _ffn(cfg, ww, xx + h)
-        if not cfg.kv_lora_rank:
-            k, v = kept
-            kept = (k.reshape(B, T, -1)[:, T - S :], v.reshape(B, T, -1)[:, T - S :])
-        return xx, (kept, routed)
+        return xx, (jax.tree.map(lambda t: kv.prompt_rows(cfg, t), kept), routed)
 
     policy = L.remat_policy(cfg.remat)
     if policy is not None:
@@ -292,8 +276,7 @@ def prefill(cfg, params, batch):
     kept = jax.tree.map(lambda *a: jnp.concatenate(a), *kept)
     zero = jnp.zeros((n_moe_layers(cfg),), jnp.int32)
     cache = {
-        "pos": jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S)),
-        "length": jnp.full((B,), T, jnp.int32),
+        **kv.prompt_state(cfg, B, T),
         "counters": _counted({k: zero for k in COUNTERS}, routed, B * T * cfg.top_k),
     }
     if cfg.kv_lora_rank:
@@ -304,59 +287,29 @@ def prefill(cfg, params, batch):
 
 
 def decode_step(cfg, params, cache, tokens):
-    if cfg.kv_lora_rank:
-        return _decode_latent(cfg, params, cache, tokens)
+    """One decode step.  Each layer reads its slice of the cache in place
+    and returns the new position's row: the latent under MLA, else its
+    keys and values."""
     B = tokens.shape[0]
-    S = cache["k"].shape[2]
-    hd = cfg.resolved_head_dim
     length = cache["length"]
     positions = length[:, None].astype(jnp.int32)
-    x = L.embed_lookup(params["emb"], tokens)
-    slot = (length % S).astype(jnp.int32)
-    barange = jnp.arange(B)
-    new_pos = cache["pos"].at[barange, slot].set(length)
-    valid = (new_pos >= 0) & (new_pos <= length[:, None])
-
-    def block(xx, scan_in):
-        ww, kc, vc = scan_in
-        h = _norm(cfg, xx, ww["ln1"])
-        q, k, v = L.attention_qkv(cfg, ww["attn"], h, positions)
-        kc = kc.at[barange, slot].set(k.reshape(B, -1))
-        vc = vc.at[barange, slot].set(v.reshape(B, -1))
-        o = L.decode_attention(
-            q, kc.reshape(B, S, cfg.n_kv_heads, hd), vc.reshape(B, S, cfg.n_kv_heads, hd), valid
-        )
-        xx = xx + o.reshape(B, 1, -1) @ ww["attn"]["wo"]
-        xx, _, routed = _ffn(cfg, ww, xx)
-        return xx, (kc, vc, routed)
-
-    x, (ks, vs, routed) = _scan_serving(cfg, block, x, params["layers"], cache["k"], cache["v"])
-    new_cache = {"k": ks, "v": vs, "pos": new_pos, "length": length + 1,
-                 "counters": _counted(cache["counters"], routed, B * cfg.top_k)}
-    return new_cache, _logits(cfg, params, x)
-
-
-def _decode_latent(cfg, params, cache, tokens):
-    """Decode under MLA.  Each layer reads its cached latents in place and
-    returns the new position's row; one update writes every layer's row
-    into the (donated) cache after the layers have run."""
-    B = tokens.shape[0]
-    lat = cache["latent"]
-    S = lat.shape[2]
-    length = cache["length"]
-    positions = length[:, None].astype(jnp.int32)
-    slot = (length % S).astype(jnp.int32)
-    barange = jnp.arange(B)
-    valid = (cache["pos"] >= 0) & (cache["pos"] < length[:, None])
+    step = kv.step(cache, cfg.sliding_window)
     x = L.embed_lookup(params["emb"], tokens)
 
     def block(xx, scan_in):
         ww, i = scan_in
         h = _norm(cfg, xx, ww["ln1"])
-        with jax.named_scope("mla"):
-            o, row = mla.decode_attention(cfg, ww["attn"], h, positions, lat[i], valid)
+        if cfg.kv_lora_rank:
+            with jax.named_scope("mla"):
+                o, lat = mla.decode_attention(cfg, ww["attn"], h, positions,
+                                              cache["latent"][i], step.mask)
+            rows = {"latent": lat}
+        else:
+            o, (k, v) = L.decode_attention_layer(cfg, ww["attn"], h, positions,
+                                                 cache["k"], cache["v"], i, step.mask)
+            rows = {"k": kv.row(k, cache["k"]), "v": kv.row(v, cache["v"])}
         xx, _, routed = _ffn(cfg, ww, xx + o)
-        return xx, (row, routed)
+        return xx, (rows, routed)
 
     rows, first = [], 0
     for stack in _stacks(params):
@@ -364,12 +317,10 @@ def _decode_latent(cfg, params, cache, tokens):
         x, (r, routed) = _scan_serving(cfg, block, x, stack, jnp.arange(first, first + n))
         rows.append(r)
         first += n
-    rows = jnp.concatenate(rows)  # (layers, B, latent_dim)
-    for b in range(B):  # in place: a scatter over (B, S) would re-lay the cache
-        lat = lax.dynamic_update_slice(lat, rows[:, b, None, None], (0, b, slot[b], 0))
+    rows = jax.tree.map(lambda *a: jnp.concatenate(a), *rows)  # (layers, B, width)
     new_cache = {
-        "latent": lat,
-        "pos": cache["pos"].at[barange, slot].set(length),
+        **kv.write_rows(cache, rows, step.slot),
+        "pos": step.pos,
         "length": length + 1,
         "counters": _counted(cache["counters"], routed, B * cfg.top_k),
     }

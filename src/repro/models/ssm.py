@@ -245,14 +245,10 @@ def mamba2_block(cfg, w, x: jax.Array, cache: Dict = None):
 # ---------------------------------------------------------------------------
 
 
-def _stack(tree, n):
-    return L.spec_map(lambda s: Spec((n,) + s.shape, ("layers",) + s.axes, s.dtype, s.init), tree)
-
-
 def param_spec(cfg) -> Dict[str, Spec]:
     return {
         **L.embed_param_spec(cfg),
-        "layers": _stack(mamba1_param_spec(cfg), cfg.n_layers),
+        "layers": L.stack_layers(mamba1_param_spec(cfg), cfg.n_layers),
         "ln_f": Spec((cfg.d_model,), ("embed",), init="ones"),
     }
 

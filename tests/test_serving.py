@@ -68,17 +68,23 @@ def test_generate_token_budget_exact():
     np.testing.assert_array_equal(np.asarray(t1), np.asarray(t4[:, :1]))
 
 
-def test_sliding_window_ring_buffer():
-    cfg = smoke_config("h2o_danube_3_4b").replace(attn_impl="naive", sliding_window=8)
+@pytest.mark.parametrize("T", [16, 13])
+@pytest.mark.parametrize("arch", ["h2o_danube_3_4b", "granite_moe_1b_a400m", "moonlight_16b_a3b"])
+def test_sliding_window_ring_buffer(arch, T):
+    """A window-bounded ring, wrapped by the prompt (evenly or not) and
+    again by decoding, attends what the full forward pass attends."""
+    cfg = smoke_config(arch).replace(attn_impl="naive", sliding_window=8)
     params = init_of(zoo.param_spec(cfg), jax.random.PRNGKey(0))
-    B, T = 1, 16
+    B = 1
     tokens = jnp.asarray(np.random.default_rng(0).integers(0, cfg.vocab_size, (B, T + 6)), jnp.int32)
     cache, _ = zoo.prefill(cfg, params, {"tokens": tokens[:, :T]})
-    assert cache["k"].shape[2] == 8  # window-bounded
+    assert cache["latent" if cfg.kv_lora_rank else "k"].shape[2] == 8  # window-bounded
     for i in range(6):
         cache, logits = zoo.decode_step(cfg, params, cache, tokens[:, T + i : T + i + 1])
     full = zoo.forward(cfg, params, {"tokens": tokens, "labels": tokens})
-    ref = (full @ params["emb"].T).astype(jnp.float32)
+    if isinstance(full, tuple):
+        full = full[0]
+    ref = (full @ output_head(cfg, params).T).astype(jnp.float32)
     np.testing.assert_allclose(
         np.asarray(logits[:, 0], np.float32), np.asarray(ref[:, T + 5], np.float32),
         rtol=0.12, atol=0.25,
@@ -160,8 +166,7 @@ ROW_CASES = {
 @pytest.mark.parametrize("case", ROW_CASES)
 def test_decode_attention_row_matches_full_cache(case):
     """The new-row attention over the cache as it was before the step equals
-    writing the row first and attending the whole cache, as decode did
-    before (float32)."""
+    writing the row first and attending the whole cache (float32)."""
     from repro.models.layers import decode_attention, decode_attention_row
 
     Hq, Hkv, window, S, lengths = ROW_CASES[case]
@@ -185,24 +190,50 @@ def test_decode_attention_row_matches_full_cache(case):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-6)
 
 
-@pytest.mark.parametrize("arch", ["llama3_2_3b", "h2o_danube_3_4b"])
+@pytest.mark.parametrize("case", ROW_CASES)
+def test_step_mask_follows_the_ring(case):
+    """A step writes position ``length`` at slot ``length % S`` and attends
+    every written slot within the window but the one it overwrites."""
+    from repro.serve import kvcache
+
+    _, _, window, S, lengths = ROW_CASES[case]
+    n = np.asarray(lengths)[:, None]
+    pos = _ring_pos(lengths, S)
+    step = kvcache.step({"pos": jnp.asarray(pos), "length": jnp.asarray(lengths, jnp.int32)},
+                        window)
+    want = (pos >= 0) & (np.arange(S) != n % S)
+    if window:
+        want &= n - pos < window
+    np.testing.assert_array_equal(np.asarray(step.slot), n[:, 0] % S)
+    np.testing.assert_array_equal(np.asarray(step.pos), _ring_pos(n[:, 0] + 1, S))
+    np.testing.assert_array_equal(np.asarray(step.mask), want)
+
+
+@pytest.mark.parametrize("arch", ["llama3_2_3b", "h2o_danube_3_4b", "granite_moe_1b_a400m",
+                                  "zamba2_1_2b", "whisper_tiny", "moonlight_16b_a3b"])
 def test_decode_step_writes_only_the_new_row(arch):
-    """No layer hands back a whole K/V cache from the layer scan, and a step
-    leaves every slot but each row's current one bit for bit as it was."""
-    from repro.serve.kvcache import grow_cache
+    """No layer hands back a whole sequence cache from the layer scan, and a
+    step leaves every slot but each row's current one bit for bit as it was."""
+    from repro.serve.kvcache import SEQ_KEYS, grow_cache
 
     cfg = smoke_config(arch).replace(n_layers=2, attn_impl="naive")
     if cfg.sliding_window:
         cfg = cfg.replace(sliding_window=8)  # a 16-token prompt wraps the ring
     params = init_of(zoo.param_spec(cfg), jax.random.PRNGKey(0))
     B, T = 2, 16
-    tokens = jnp.asarray(np.random.default_rng(0).integers(0, cfg.vocab_size, (B, T + 1)),
-                         jnp.int32)
-    cache, _ = zoo.prefill(cfg, params, {"tokens": tokens[:, :T]})
+    rng = np.random.default_rng(0)
+    tokens = jnp.asarray(rng.integers(0, cfg.vocab_size, (B, T + 1)), jnp.int32)
+    batch = {"tokens": tokens[:, :T]}
+    if cfg.family == "encdec":
+        batch["audio_embeds"] = jnp.asarray(
+            rng.standard_normal((B, cfg.enc_seq, cfg.d_model)) * 0.05, jnp.float32
+        ).astype(jnp.bfloat16)
+    cache, _ = zoo.prefill(cfg, params, batch)
     cache = grow_cache(cache, 4, window=cfg.sliding_window)
     step = lambda c: zoo.decode_step(cfg, params, c, tokens[:, T:])
 
-    _, S, kvd = cache["k"].shape[1:]
+    keys = [k for k in SEQ_KEYS if k in cache]
+    assert keys
     jaxpr = jax.make_jaxpr(step)(cache)
 
     def scan_outputs(jx):
@@ -214,11 +245,14 @@ def test_decode_step_writes_only_the_new_row(arch):
 
     shapes = list(scan_outputs(jaxpr.jaxpr))
     assert shapes, "decode_step walks its layers with lax.scan"
-    assert not [s for s in shapes if tuple(s[-2:]) == (S, kvd)], shapes
+    for key in keys:
+        S, width = cache[key].shape[2:]
+        assert not [s for s in shapes if tuple(s[-2:]) == (S, width)], (key, shapes)
 
     new, _ = step(cache)
-    slot = np.asarray(cache["length"]) % S
-    for key in ("k", "v"):
+    for key in keys:
+        S = cache[key].shape[2]
+        slot = np.asarray(cache["length"]) % S
         old, got = np.asarray(cache[key]), np.asarray(new[key])
         keep = np.ones(old.shape[1:3], bool)
         keep[np.arange(B), slot] = False
